@@ -5,7 +5,7 @@ against millions of lanes" while materializing full [L] planes per round
 (a broadcast quantile gather + one whole-plane copy per `.at[].set`).
 The scatter path (kernels.ops.frugal_update_sparse, DESIGN.md §13) gathers
 only the K event lanes, ticks them, scatters back in place (donated
-buffers on CPU, the program-generic Pallas kernel on TPU).
+buffers, on every platform).
 
 Measured here, CPU/jnp donated path:
 

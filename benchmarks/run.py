@@ -18,6 +18,9 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
 
+    from repro.configs.platform import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (
         bench_static_cauchy, bench_dynamic_cauchy, bench_groupby_tcp,
         bench_combined_stream, bench_groupby_twitter,

@@ -447,9 +447,9 @@ class QuantileFleet:
                           check_duplicates: bool = False) -> "QuantileFleet":
         """O(events) event round: gather the named lanes, tick them, scatter
         back IN PLACE — a handful of events against millions of lanes never
-        does O(L) work (kernels.ops.frugal_update_sparse: the gather→tick→
-        scatter Pallas kernel on TPU, the donation-aware jitted scatter pair
-        elsewhere). Requires a per-lane cursor; `lanes` must not repeat
+        does O(L) work (kernels.ops.frugal_update_sparse: the
+        donation-aware jitted gather→tick→scatter pair). Requires a
+        per-lane cursor; `lanes` must not repeat
         within one call (split same-lane events into successive rounds, in
         arrival order — serve.SLOFleet.flush does exactly this). Lanes with
         mask 0 scatter their own unchanged state back — items there are

@@ -39,6 +39,8 @@ GPU_XLA_FLAGS = (
 )
 
 _PLATFORMS = ("cpu", "gpu", "tpu")
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def set_platform(platform: str = "cpu") -> None:
@@ -75,30 +77,37 @@ def set_cpu_devices(n: int) -> None:
 def detect_platform(device=None) -> str:
     """The local device's platform string: 'tpu' | 'gpu' | 'cpu'.
 
-    Never raises: device-init failure reads as 'cpu' (the conservative
-    dispatch — the jnp scan runs everywhere)."""
-    try:
-        if device is None:
-            import jax
+    A device-init failure raises: reading it as 'cpu' would quietly run
+    the jnp scan on a machine that was meant to use its accelerator."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        return str(device.platform)
-    except Exception:  # pragma: no cover - device init failure
-        return "cpu"
+        device = jax.devices()[0]
+    return str(device.platform)
 
 
 def detect_device_kind(device=None) -> str:
     """The local device's hardware kind string (e.g. 'TPU v5 lite',
     'NVIDIA H100 80GB HBM3', 'cpu') — what roofline/analysis.py matches
-    against its per-platform registry."""
-    try:
-        if device is None:
-            import jax
+    against its per-platform registry. Raises on device-init failure."""
+    if device is None:
+        import jax
 
-            device = jax.devices()[0]
-        return str(getattr(device, "device_kind", device.platform))
-    except Exception:  # pragma: no cover - device init failure
-        return "cpu"
+        device = jax.devices()[0]
+    return str(getattr(device, "device_kind", device.platform))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else ``<repo>/.jax_cache``. The
+    path is fixed because it is part of the cache key: a directory that
+    moves between runs never hits. Call at entry-point top."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def compiled_kernel_platforms() -> tuple:

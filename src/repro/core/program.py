@@ -290,9 +290,12 @@ def _tick_2u(prog, planes, item, u, ctx):
 def _tick_2u_decay(prog, planes, item, u, ctx):
     # alpha/floor arrive as f32 BIT PATTERNS in int32 scalar slots (SMEM on
     # TPU) and are bitcast back here, so every backend multiplies by the
-    # identical float.
-    alpha = jax.lax.bitcast_convert_type(ctx.scalars[0], jnp.float32)
-    floor = jax.lax.bitcast_convert_type(ctx.scalars[1], jnp.float32)
+    # identical float. The bitcast runs on a vector of the item's shape:
+    # Mosaic has no scalar bitcast.
+    shape = jnp.shape(item)
+    alpha, floor = (
+        jax.lax.bitcast_convert_type(jnp.broadcast_to(s, shape), jnp.float32)
+        for s in ctx.scalars[:2])
     st = drift_mod.decay2u_update(frugal.Frugal2UState(*planes), item, u,
                                   ctx.quantile, alpha, floor)
     return (st.m, st.step, st.sign)

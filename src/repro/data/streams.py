@@ -22,7 +22,7 @@ scales non-integer domains — we keep floats, the algorithms only compare).
 """
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import Iterator, List, Tuple
 
 import numpy as np
 
@@ -93,6 +93,44 @@ def tcp_like_group_streams(
                 x = x * np.where(phase > 0, rng.uniform(4.0, 12.0), 1.0)
             streams.append(x)
     return [s for s in streams if len(s) >= min_len]
+
+
+def flow_size_chunks(
+    num_groups: int,
+    num_chunks: int,
+    chunk_t: int,
+    rng: np.random.Generator | None = None,
+) -> Iterator[np.ndarray]:
+    """§7.2 flow sizes at fleet scale, as dense [chunk_t, num_groups] float32
+    blocks: group g draws lognormal(mu_g, sigma_g) items with the per-site
+    parameters of tcp_like_group_streams (mu ~ U(5.5, 9), sigma ~
+    U(0.8, 1.4)). One float32 normal draw per item, exponentiated in place,
+    so a 2^22-group chunk costs about a second of host time."""
+    rng = rng or np.random.default_rng(1)
+    mu = rng.uniform(5.5, 9.0, num_groups).astype(np.float32)
+    sigma = rng.uniform(0.8, 1.4, num_groups).astype(np.float32)
+    for _ in range(num_chunks):
+        x = rng.standard_normal((chunk_t, num_groups), dtype=np.float32)
+        x *= sigma
+        x += mu
+        np.exp(x, out=x)
+        yield x
+
+
+def zipf_keys(
+    num_keys: int,
+    size: int,
+    s: float = 0.99,
+    rng: np.random.Generator | None = None,
+) -> np.ndarray:
+    """`size` draws of key ids in [0, num_keys) with P(k) ∝ 1 / (k+1)^s —
+    bounded Zipf by inverse CDF, so s <= 1 works (YCSB's request
+    distribution uses s = 0.99; numpy's own zipf needs s > 1)."""
+    rng = rng or np.random.default_rng(2)
+    cdf = np.cumsum(np.arange(1, num_keys + 1, dtype=np.float64) ** -s)
+    cdf /= cdf[-1]
+    keys = np.searchsorted(cdf, rng.random(size), side="right")
+    return np.minimum(keys, num_keys - 1).astype(np.int64)
 
 
 def combined_month_stream(

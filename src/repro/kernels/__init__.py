@@ -6,14 +6,12 @@
                      workhorse), the Mosaic/TPU double-buffered-DMA path
                      (state VMEM-resident for the whole stream, items
                      streamed HBM→VMEM one tile ahead), and the Triton/GPU
-                     body (full T loop per CTA) — plus the event-round
-                     scatter kernel (gather→tick→scatter against resident
-                     aliased state, DESIGN.md §13).
+                     body (full T loop per CTA).
   ops.py           — the single jit'd blocked/auto entry-point pair:
                      padding, dtype, packing, per-platform compiled-kernel
                      dispatch with roofline-autotuned blocks; and
                      frugal_update_sparse, the O(events) event round
-                     (donation-aware two-phase jnp scatter off-TPU).
+                     (donation-aware two-phase XLA scatter, every platform).
                      (Plus ValueError stubs for the removed pre-program
                      entry points, naming the replacement.)
   ref.py           — pure-jnp lax.scan oracles for bit-exact validation.
@@ -23,7 +21,6 @@ from .frugal_update import (
     frugal_program_pallas,
     frugal_program_pallas_dma,
     frugal_program_pallas_gpu,
-    frugal_program_scatter_pallas,
 )
 from .ops import (
     block_override,
@@ -56,7 +53,6 @@ __all__ = [
     "frugal_program_pallas",
     "frugal_program_pallas_dma",
     "frugal_program_pallas_gpu",
-    "frugal_program_scatter_pallas",
     "frugal_update_auto",
     "frugal_update_blocked",
     "frugal_update_sparse",

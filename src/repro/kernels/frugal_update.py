@@ -49,24 +49,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import frugal
 from repro.core import rng as crng
+from repro.roofline import kernel_model
 
 Array = jax.Array
 
-# jax renamed TPUCompilerParams -> CompilerParams across versions.
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-
 
 def _compiler_params():
-    return _CompilerParams(dimension_semantics=("parallel", "arbitrary"))
+    return pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"))
 
 
 def _lane_ids(g_blk, block_g, g0):
-    """Absolute lane index per VPU lane ([block_g] int32; 2-D iota for
-    Mosaic). `g0` is the fleet-global index of array column 0 — nonzero when
-    this call ingests one shard of a lane-sharded fleet
-    (parallel/group_sharding.py), so every shard hashes uniforms at the same
-    (seed, t, lane) keys as the unsharded fleet."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_g), 1)[0]
+    """Absolute lane index per VPU lane, [1, block_g] int32. Every value in
+    a kernel body stays 2-D: Mosaic refuses rank-1 vectors. `g0` is the
+    fleet-global index of array column 0 — nonzero when this call ingests
+    one shard of a lane-sharded fleet (parallel/group_sharding.py), so
+    every shard hashes uniforms at the same (seed, t, lane) keys as the
+    unsharded fleet."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, (1, block_g), 1)
     return g0 + g_blk * block_g + iota
 
 
@@ -89,16 +88,16 @@ def _program_kernel(seed_ref, q_ref, items_ref, *state_refs, program,
         for i_ref, o_ref in zip(in_refs, out_refs):
             o_ref[...] = i_ref[...]
 
-    q = q_ref[0, :]
+    q = q_ref[...]
     seed = seed_ref[0]
     t0 = seed_ref[1] + t_blk * block_t          # absolute stream tick of row 0
     g_ids = _lane_ids(g_blk, block_g, seed_ref[2])
     scalars = tuple(seed_ref[3 + k] for k in range(len(layout.scalar_names)))
 
-    planes0 = layout.unpack_words(tuple(r[0, :] for r in out_refs))
+    planes0 = layout.unpack_words(tuple(r[...] for r in out_refs))
 
     def body(i, planes):
-        it = items_ref[i, :]
+        it = items_ref[pl.ds(i, 1), :]
         r = crng.counter_uniform(seed, t0 + i, g_ids)
         ctx = frugal.TickCtx(quantile=q, t=t0 + i, seed=seed, lanes=g_ids,
                              scalars=scalars)
@@ -106,7 +105,7 @@ def _program_kernel(seed_ref, q_ref, items_ref, *state_refs, program,
 
     planes = jax.lax.fori_loop(0, block_t, body, planes0)
     for r, w in zip(out_refs, layout.pack_planes(planes)):
-        r[0, :] = w
+        r[...] = w
 
 
 def _program_kernel_dma(seed_ref, q_ref, items_hbm, *refs, program,
@@ -142,11 +141,11 @@ def _program_kernel_dma(seed_ref, q_ref, items_hbm, *refs, program,
 
     item_dma(0, 0).start()
 
-    q = q_ref[0, :]
+    q = q_ref[...]
     seed = seed_ref[0]
     g_ids = _lane_ids(gi, block_g, seed_ref[2])
     scalars = tuple(seed_ref[3 + k] for k in range(len(layout.scalar_names)))
-    planes0 = layout.unpack_words(tuple(r[0, :] for r in in_refs))
+    planes0 = layout.unpack_words(tuple(r[...] for r in in_refs))
 
     def chunk(ci, planes):
         slot = jax.lax.rem(ci, 2)
@@ -159,7 +158,7 @@ def _program_kernel_dma(seed_ref, q_ref, items_hbm, *refs, program,
         t0 = seed_ref[1] + ci * block_t
 
         def body(i, pls):
-            it = scratch[slot, i, :]
+            it = scratch[slot, pl.ds(i, 1), :]
             r = crng.counter_uniform(seed, t0 + i, g_ids)
             ctx = frugal.TickCtx(quantile=q, t=t0 + i, seed=seed,
                                  lanes=g_ids, scalars=scalars)
@@ -169,7 +168,7 @@ def _program_kernel_dma(seed_ref, q_ref, items_hbm, *refs, program,
 
     planes = jax.lax.fori_loop(0, n_chunks, chunk, planes0)
     for r, w in zip(out_refs, layout.pack_planes(planes)):
-        r[0, :] = w
+        r[...] = w
 
 
 def _program_kernel_gpu(meta_ref, q_ref, items_ref, *state_refs, program,
@@ -178,8 +177,9 @@ def _program_kernel_gpu(meta_ref, q_ref, items_ref, *state_refs, program,
     parallel CTAs with no sequential-revisit semantics, so the (G, T) grid
     of the TPU kernel is invalid here: the grid is (G_blocks,) and the full
     T loop runs in-kernel. Triton refs are lazy GMEM pointer views, so the
-    per-tick row load ``items_ref[i, :]`` reads [block_g] floats straight
-    from HBM (L2-cached across the warp) — no DMA choreography to write.
+    per-tick row load ``items_ref[pl.ds(i, 1), :]`` reads [1, block_g]
+    floats straight from HBM (L2-cached across the warp) — no DMA
+    choreography to write.
     PrefetchScalarGridSpec is TPU-only, so the meta vector rides as a
     regular [1, n] operand. No pltpu symbol is touched on this path, which
     also makes it interpret-testable on CPU."""
@@ -188,16 +188,16 @@ def _program_kernel_gpu(meta_ref, q_ref, items_ref, *state_refs, program,
     in_refs, out_refs = state_refs[:nw], state_refs[nw:]
     g_blk = pl.program_id(0)
 
-    q = q_ref[0, :]
+    q = q_ref[...]
     seed = meta_ref[0, 0]
     t0 = meta_ref[0, 1]
     g_ids = _lane_ids(g_blk, block_g, meta_ref[0, 2])
     scalars = tuple(meta_ref[0, 3 + k]
                     for k in range(len(layout.scalar_names)))
-    planes0 = layout.unpack_words(tuple(r[0, :] for r in in_refs))
+    planes0 = layout.unpack_words(tuple(r[...] for r in in_refs))
 
     def body(i, planes):
-        it = items_ref[i, :]
+        it = items_ref[pl.ds(i, 1), :]
         r = crng.counter_uniform(seed, t0 + i, g_ids)
         ctx = frugal.TickCtx(quantile=q, t=t0 + i, seed=seed, lanes=g_ids,
                              scalars=scalars)
@@ -205,7 +205,7 @@ def _program_kernel_gpu(meta_ref, q_ref, items_ref, *state_refs, program,
 
     planes = jax.lax.fori_loop(0, t_total, body, planes0)
     for r, w in zip(out_refs, layout.pack_planes(planes)):
-        r[0, :] = w
+        r[...] = w
 
 
 def _seed_operand(seed, t_offset, g_offset, scalars=()) -> Array:
@@ -216,126 +216,6 @@ def _seed_operand(seed, t_offset, g_offset, scalars=()) -> Array:
              jnp.asarray(g_offset, jnp.int32)]
     parts += [jnp.asarray(s, jnp.int32) for s in scalars]
     return jnp.stack(parts)
-
-
-def _scatter_kernel(meta_ref, lanes_ref, mask_ref, items_ref, q_ref,
-                    *state_refs, program, block_k):
-    """Gather→tick→scatter body: one sequential pass over this grid step's
-    event slots. Per event, the lane's planes are loaded from the full [L]
-    state refs at a dynamic index, ticked once with the lane's own
-    counter-hash uniform, and stored back — O(events) loads/stores total,
-    never an O(L) pass. The state refs are input/output-ALIASED full
-    arrays (memory space ANY: they stay put; nothing blocks them through
-    VMEM), so grid steps revisit the same buffers ("arbitrary" semantics).
-
-    Events are pre-segmented by the caller: within one dispatch no masked-in
-    lane repeats (duplicate stores would race in a parallel schedule), and
-    masked-out pad slots carry NaN items — their load/tick/store round-trips
-    the lane's state bit-exactly, so padding never perturbs anything.
-    """
-    layout = program.layout
-    np_ = layout.num_planes
-    n_state = np_ + 1
-    # state_refs = n_state inputs then n_state outputs; the outputs ALIAS
-    # the inputs (same buffers), so the body reads and writes only the
-    # output refs — no copy-in pass (which would be the O(L) work this
-    # kernel exists to avoid).
-    out_refs = state_refs[n_state:]
-    plane_refs, ticks_ref = out_refs[:np_], out_refs[np_]
-    blk = pl.program_id(0)
-    seed = meta_ref[0]
-    g0 = meta_ref[2]   # the dense family's operand layout; slot 1 (t_offset)
-                       # is unused — event ticks come from the [L] clock
-    scalars = tuple(meta_ref[3 + k] for k in range(len(layout.scalar_names)))
-
-    def body(k, carry):
-        e = blk * block_k + k
-        lane = lanes_ref[e]
-        planes_e = tuple(r[pl.ds(lane, 1)] for r in plane_refs)
-        tick = ticks_ref[pl.ds(lane, 1)]
-        item = items_ref[0, pl.ds(e, 1)]
-        q = q_ref[0, pl.ds(e, 1)]
-        g_id = g0 + lane
-        u = crng.counter_uniform(seed, tick, g_id)
-        ctx = frugal.TickCtx(quantile=q, t=tick, seed=seed, lanes=g_id,
-                             scalars=scalars)
-        out = program.run_tick(planes_e, item, u, ctx)
-        for r, o in zip(plane_refs, out):
-            r[pl.ds(lane, 1)] = o
-        ticks_ref[pl.ds(lane, 1)] = tick + mask_ref[e]
-        return carry
-
-    jax.lax.fori_loop(0, block_k, body, 0)
-
-
-def frugal_program_scatter_pallas(
-    program,          # core.program.LaneProgram (STATIC compile key —
-                      # callers pass family_base)
-    lanes: Array,     # [K] int32 event lane ids (masked-in ids distinct)
-    items: Array,     # [K] float32 (NaN where mask == 0)
-    mask: Array,      # [K] int32 — 1 advances the lane clock, 0 is padding
-    planes,           # layout.num_planes UNPACKED plane arrays, each [L]
-    ticks: Array,     # [L] int32 per-lane clock
-    quantile: Array,  # [K] float32 — each event lane's own target, gathered
-    seed,             # int32 counter RNG seed
-    scalars=(),       # program's dynamic int32 scalar operands
-    *,
-    g_offset=0,       # absolute lane index of state row 0 (sharded fleets)
-    block_k: int = 128,
-    interpret: bool = False,
-):
-    """O(events) sparse event round for ANY registered lane program.
-
-    The dense family streams [T, G] blocks through VMEM tiles; this kernel
-    is its event-mode sibling: K event slots against L resident lanes,
-    K % block_k == 0 (pad with mask-0 NaN slots on any lane that has no
-    event this round). State rides UNPACKED planes — the serialized
-    (step,sign) word packing exists to halve O(L)-scale HBM block traffic,
-    but here traffic is O(K); per-event repacking would buy nothing and
-    packing on dispatch would cost the O(L) pass this kernel exists to
-    avoid. Returns (planes, ticks) updated.
-
-    Bit-exactness: the tick expression, uniform keying (seed, per-lane
-    tick, absolute lane id) and NaN no-op contract are identical to the
-    dense kernel and the jnp scan, so a sparse round reproduces the dense
-    `tick_lanes` round bit-for-bit (tests/conftest.py sweeps every
-    registered program over both paths).
-    """
-    layout = program.layout
-    (k,) = lanes.shape
-    assert k % block_k == 0, (k, block_k)
-    assert len(planes) == layout.num_planes, (len(planes), layout.num_planes)
-    grid = (k // block_k,)
-
-    # Full-array state blocks, revisited by every grid step; events/quantile
-    # ride [1, K] VMEM rows (the kernel indexes columns dynamically).
-    state_spec = pl.BlockSpec(memory_space=getattr(pltpu, "ANY", None)
-                              or pltpu.TPUMemorySpace.ANY)
-    event_spec = pl.BlockSpec((1, k), lambda i, *_: (0, 0))
-
-    n_state = layout.num_planes + 1    # planes + ticks
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,         # meta, lanes, mask
-        grid=grid,
-        in_specs=[event_spec, event_spec] + [state_spec] * n_state,
-        out_specs=[state_spec] * n_state,
-    )
-    # Input operand i (counting the scalar-prefetch operands first) aliases
-    # output i - 5: the planes and ticks update in place.
-    aliases = {5 + i: i for i in range(n_state)}
-    meta = _seed_operand(seed, 0, g_offset, scalars)
-    outs = pl.pallas_call(
-        functools.partial(_scatter_kernel, program=program, block_k=block_k),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype) for p in planes]
-        + [jax.ShapeDtypeStruct(ticks.shape, ticks.dtype)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(meta, jnp.asarray(lanes, jnp.int32), jnp.asarray(mask, jnp.int32),
-      items[None, :], quantile[None, :], *planes, ticks)
-    return tuple(outs[:-1]), outs[-1]
 
 
 def frugal_program_pallas(
@@ -421,8 +301,7 @@ def frugal_program_pallas_dma(
     n_chunks = t // block_t
 
     state_spec = pl.BlockSpec((1, block_g), lambda gi, *_: (0, gi))
-    any_spec = pl.BlockSpec(memory_space=getattr(pltpu, "ANY", None)
-                            or pltpu.TPUMemorySpace.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -441,7 +320,9 @@ def frugal_program_pallas_dma(
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((1, g), dt)
                    for dt in layout.word_dtypes],
-        compiler_params=_CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=kernel_model.VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(_seed_operand(seed, t_offset, g_offset, scalars), quantile[None, :],
       items, *[w[None, :] for w in words])

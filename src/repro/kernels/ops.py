@@ -44,7 +44,6 @@ from .frugal_update import (
     frugal_program_pallas,
     frugal_program_pallas_dma,
     frugal_program_pallas_gpu,
-    frugal_program_scatter_pallas,
 )
 
 Array = jax.Array
@@ -52,10 +51,6 @@ Array = jax.Array
 # compiled lowering per platform: Mosaic DMA kernel on TPU, Triton body on
 # GPU, the (G, T) revisit grid as the interpret-mode/test workhorse
 _PLATFORM_KERNEL = {"tpu": "dma", "gpu": "gpu"}
-
-
-def _on_tpu() -> bool:
-    return detect_platform() == "tpu"
 
 
 def _compiled_refusal(entry: str) -> ValueError:
@@ -316,8 +311,7 @@ _sparse_scatter_donated = jax.jit(_sparse_round,
 
 def frugal_update_sparse(lanes, items, mask, planes, ticks, quantile,
                          seed, scalars=(), *, program, g_offset=0,
-                         donate=False, block_k: int = 128,
-                         interpret=None):
+                         donate=False):
     """Program-parameterized O(events) event round: gather the `lanes`
     rows of `planes`/`ticks`, tick them once, scatter back.
 
@@ -333,19 +327,11 @@ def frugal_update_sparse(lanes, items, mask, planes, ticks, quantile,
     is the intended caller). With donate=False the round stays one fused
     executable but XLA copies each [L] plane to preserve the inputs.
 
-    On TPU the round runs as the gather→tick→scatter Pallas kernel
-    (kernels/frugal_update.py) against resident state; elsewhere as the
-    jitted jnp scatter pair. Bit-identical either way.
-
-    `interpret` arms: None (default) picks per platform — the compiled
-    scatter kernel on TPU, the jitted XLA scatter pair elsewhere (native
-    scatters ARE the O(events) path on cpu/gpu). True forces the scatter
-    kernel in interpret mode anywhere (test harness). False demands the
-    compiled scatter kernel, which is a Mosaic-only lowering — off TPU it
-    raises a ValueError naming frugal_update_auto instead of crashing in
-    the TPU lowering (the old dispatch forced the Pallas path for ANY
-    non-None `interpret`, so an explicit False off-TPU went down in
-    flames).
+    The round is XLA's own gather/scatter on every platform, TPU included.
+    There is no Pallas lowering: Mosaic DMAs a 1-D HBM array only in whole
+    1024-element tiles, so a kernel would read, tick and write back one
+    tile per event, one event at a time (events sharing a tile would
+    race) — two DMA round trips per event.
     """
     base = program_mod.family_base(program.kernel_family)
     scalars = tuple(jnp.asarray(v, jnp.int32) for v in scalars) \
@@ -354,31 +340,6 @@ def frugal_update_sparse(lanes, items, mask, planes, ticks, quantile,
     mask = jnp.asarray(mask, jnp.int32)
     items = jnp.asarray(items, planes[0].dtype)
     seed = jnp.asarray(seed, jnp.int32)
-    if interpret is None:
-        use_pallas = _on_tpu()
-    elif interpret is False and not _on_tpu():
-        raise _compiled_refusal("frugal_update_sparse")
-    else:
-        use_pallas = True
-    if use_pallas:
-        k = lanes.shape[0]
-        kp = (-k) % block_k
-        if kp:
-            # Pad with mask-0 NaN slots on the first event's lane: a NaN
-            # tick round-trips state bit-exactly and a duplicate STORE of
-            # an unchanged value is safe under the kernel's sequential
-            # ("arbitrary") grid semantics.
-            lanes = jnp.concatenate(
-                [lanes, jnp.broadcast_to(lanes[:1], (kp,))])
-            items = jnp.concatenate(
-                [items, jnp.full((kp,), jnp.nan, items.dtype)])
-            mask = jnp.concatenate([mask, jnp.zeros((kp,), jnp.int32)])
-        q = jnp.asarray(quantile, planes[0].dtype)
-        q_s = q[lanes] if q.ndim else jnp.broadcast_to(q, lanes.shape)
-        return frugal_program_scatter_pallas(
-            base, lanes, items, mask, tuple(planes), ticks, q_s, seed,
-            scalars, g_offset=g_offset, block_k=block_k,
-            interpret=bool(interpret))
     ticks_s = _sparse_gather_ticks(ticks, lanes)
     step = _sparse_scatter_donated if donate else _sparse_scatter
     return step(lanes, items, mask, tuple(planes), ticks, ticks_s,

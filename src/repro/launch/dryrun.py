@@ -124,8 +124,6 @@ def _compile_and_measure(arch, shape, mesh, kind, overrides=None,
     out["compile_s"] = round(t3 - t2, 2)
     try:
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):
-            cost = cost[0]
     except Exception as e:  # pragma: no cover
         cost, out["cost_error"] = {}, str(e)
     out["flops"] = float(cost.get("flops", 0.0))

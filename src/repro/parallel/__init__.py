@@ -16,7 +16,6 @@ from .topology import (
 from .mesh2d import (
     Mesh2DFleet,
     merge_replica_planes,
-    shard_map_compat,
 )
 from .group_sharding import (
     GROUP_AXIS,
@@ -35,7 +34,6 @@ __all__ = [
     "TopologySpec",
     "Mesh2DFleet",
     "merge_replica_planes",
-    "shard_map_compat",
     "GROUP_AXIS",
     "ShardedGroupFleet",
     "group_mesh",

@@ -38,7 +38,7 @@ from repro.core import streaming
 from repro.core.drift import is_windowed as drift_is_windowed
 from repro.core.sketch import GroupedQuantileSketch, PackedSketchState
 from repro.resilience import chaos
-from .mesh2d import pad_lane_fill, shard_map_compat
+from .mesh2d import pad_lane_fill
 
 Array = jax.Array
 
@@ -92,11 +92,11 @@ def _sharded_ingest_fn(mesh: Mesh, axis: str, program, shard_g: int,
                                      g_offset=g0, t_offset=t0)
         return out.planes()
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, axis), state_spec, P(), P(), P())
         + (state_spec,) * n,
-        out_specs=(state_spec,) * n)
+        out_specs=(state_spec,) * n, check_vma=False)
     return jax.jit(fn)
 
 

@@ -69,23 +69,6 @@ from .topology import DATA_AXIS, LANE_AXIS, TopologySpec
 
 Array = jax.Array
 
-# jax.shard_map (kwarg check_vma) landed after 0.4.x; older jax ships it as
-# jax.experimental.shard_map.shard_map with the kwarg named check_rep.
-# (Moved here from pipeline_parallel.py — the topology path owns it now.)
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:  # pragma: no cover - exercised on jax<0.5 installs
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check=False):
-    """Version-portable shard_map with replication checking disabled."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check})
-
-
 def pad_lane_fill(layout, field: str) -> float:
     """Dummy state for pad lanes: the program layout's fills, plus the
     quantile plane (not a layout plane — it rides every sketch)."""
@@ -163,12 +146,34 @@ def _mesh2d_ingest_fn(mesh: Mesh, program, shard_g: int):
         sk = streaming.ingest_slabs(sk, slabs[0], offsets[0], seed, g0)
         return tuple(p[None] for p in sk.planes())
 
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(DATA_AXIS, None, None, LANE_AXIS), P(DATA_AXIS, None),
                   state_spec, P(), P()) + (state_spec,) * n,
-        out_specs=(state_spec,) * n)
+        out_specs=(state_spec,) * n, check_vma=False)
     return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _slab_fn(mesh: Optional[Mesh]):
+    """[T, cols] items -> [R, S, chunk_t, Gp] replica slabs: the Q-fold lane
+    fan-out, NaN pad lanes and rows, and the chunk→replica gather of
+    Mesh2DFleet._slab_layout. On a mesh the output is laid out
+    P(data, -, -, lanes)."""
+    def build(items, idx, *, fan, gp, lead, pad_rows, chunk_t):
+        if fan > 1:
+            items = jnp.repeat(items, fan, axis=1)
+        items = jnp.pad(items, ((lead, pad_rows), (0, gp - items.shape[1])),
+                        constant_values=jnp.nan)
+        chunks = items.reshape(-1, chunk_t, gp)
+        slabs = jnp.take(chunks, idx.reshape(-1), axis=0)
+        return slabs.reshape(idx.shape + (chunk_t, gp))
+
+    out = None if mesh is None else NamedSharding(
+        mesh, P(DATA_AXIS, None, None, LANE_AXIS))
+    return jax.jit(build, out_shardings=out,
+                   static_argnames=("fan", "gp", "lead", "pad_rows",
+                                    "chunk_t"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -189,8 +194,8 @@ def _mesh2d_sync_fn(mesh: Mesh, program):
             out.append(_fold_domain(stack, domains[f], jnp)[None])
         return tuple(out)
 
-    fn = shard_map_compat(body, mesh=mesh, in_specs=(state_spec,) * n,
-                          out_specs=(state_spec,) * n)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(state_spec,) * n,
+                       out_specs=(state_spec,) * n, check_vma=False)
     return jax.jit(fn)
 
 
@@ -318,9 +323,10 @@ class Mesh2DFleet:
                            lanes_per_group=lanes_per_group)
 
     # ---------------------------------------------------------------- ingest
-    def _pad_items(self, items) -> Array:
+    def _check_items(self, items) -> Tuple[Array, int]:
         """[T, G] group columns (fanned Q-fold), [T, L] lanes, or [T, Gp]
-        pre-padded — NaN pad lanes, same contract as the 1-D fleet."""
+        pre-padded; returns (items, fan-out) — _slab_fn does the fan-out
+        and the NaN pad lanes, same contract as the 1-D fleet."""
         items = jnp.asarray(items, jnp.float32)
         if items.ndim == 1:
             items = items[:, None]
@@ -330,12 +336,7 @@ class Mesh2DFleet:
         ok = {self.num_groups, gp} | ({cols} if q > 1 else set())
         if items.ndim != 2 or items.shape[1] not in ok:
             raise ValueError(f"items shape {items.shape} != [T, {cols}]")
-        if q > 1 and items.shape[1] == cols:
-            items = jnp.repeat(items, q, axis=1)
-        if items.shape[1] != gp:
-            items = jnp.pad(items, ((0, 0), (0, gp - items.shape[1])),
-                            constant_values=jnp.nan)
-        return items
+        return items, (q if q > 1 and items.shape[1] == cols else 1)
 
     def _slab_layout(self, t: int, t0: int, chunk_t: int):
         """Host-side chunk→replica assignment off the ABSOLUTE tick.
@@ -384,31 +385,29 @@ class Mesh2DFleet:
             assert key is not None, "need key= or seed="
             seed = crng.seed_from_key(key)
         t0 = crng.wrap_i32(int(t_offset))
-        items = self._pad_items(items)
-        t, gp = items.shape
+        items, fan = self._check_items(items)
+        t = items.shape[0]
         if t == 0:
             return self
         lead, pad_rows, idx, offsets = self._slab_layout(t, t0, chunk_t)
-        items = jnp.pad(items, ((lead, pad_rows), (0, 0)),
-                        constant_values=jnp.nan)
-        chunks = items.reshape(-1, chunk_t, gp)
-        slabs = jnp.take(chunks, jnp.asarray(idx.reshape(-1), jnp.int32),
-                         axis=0)
-        slabs = slabs.reshape(idx.shape[0], idx.shape[1], chunk_t, gp)
+        layout = dict(fan=fan, gp=self.padded_groups, lead=lead,
+                      pad_rows=pad_rows, chunk_t=chunk_t)
         offsets = jnp.asarray(offsets, jnp.int32)
         seed = jnp.asarray(seed, jnp.int32)
         g0 = jnp.asarray(crng.wrap_i32(int(g_offset)), jnp.int32)
         sk = self.sketch
         if self.mode == "shard_map":
             mesh = self.mesh()
-            slabs = jax.device_put(
-                slabs, NamedSharding(mesh, P(DATA_AXIS, None, None,
-                                             LANE_AXIS)))
+            # The slabs are built already sharded: each device makes only
+            # its replica's lane shard, never the whole [R, S, chunk_t, Gp].
+            items = jax.device_put(items, NamedSharding(mesh, P()))
+            slabs = _slab_fn(mesh)(items, idx.astype(np.int32), **layout)
             offsets = jax.device_put(
                 offsets, NamedSharding(mesh, P(DATA_AXIS, None)))
             fn = _mesh2d_ingest_fn(mesh, sk.program, self.shard_groups)
             planes = fn(slabs, offsets, sk.quantile, seed, g0, *sk.planes())
         else:
+            slabs = _slab_fn(None)(items, idx.astype(np.int32), **layout)
             fn = _loop_ingest_fn(sk.program)
             outs = []
             for r in range(self.data_replicas):
@@ -572,4 +571,4 @@ class Mesh2DFleet:
 
 
 __all__ = ["DATA_AXIS", "LANE_AXIS", "Mesh2DFleet", "TopologySpec",
-           "merge_replica_planes", "pad_lane_fill", "shard_map_compat"]
+           "merge_replica_planes", "pad_lane_fill"]

@@ -9,13 +9,8 @@ tier — `pipeline_forward` / `bubble_fraction` were only ever exercised by
 their own subprocess test. They remain importable as ValueError stubs
 naming the replacement (same convention as serve.engine.RouteStats; pinned
 in tests/test_deprecations.py).
-
-`shard_map_compat` — the one genuinely load-bearing thing this module held
-— now lives in parallel.mesh2d (re-exported here for stale imports).
 """
 from __future__ import annotations
-
-from .mesh2d import shard_map_compat  # noqa: F401  (back-compat re-export)
 
 _REMOVED = (
     "parallel.pipeline_parallel.{name} was removed: the GPipe microbatch "
@@ -34,4 +29,4 @@ def bubble_fraction(*args, **kwargs):
     raise ValueError(_REMOVED.format(name="bubble_fraction"))
 
 
-__all__ = ["shard_map_compat", "pipeline_forward", "bubble_fraction"]
+__all__ = ["pipeline_forward", "bubble_fraction"]

@@ -22,9 +22,11 @@ Axes:
 
 `devices=None` resolves lazily against jax.devices() (under jax.distributed
 that is the global device list, so multi-host placement is the same
-spelling). A 2-D topology that does not fit the visible devices falls back
-to a sequential loop over replicas — bit-identical to the sharded
-execution, which is how single-device CI covers every topology.
+spelling). On the host CPU backend, a 2-D topology that does not fit the
+visible devices runs as a sequential loop over replicas — bit-identical
+to the sharded execution, which is how single-device CI covers every
+topology. On an accelerator too few devices is an error: a loop there
+would hide a missing chip.
 
 FleetSpec normalizes the legacy spellings onto this type (with a
 DeprecationWarning) so old and new specs compare EQUAL — the migration
@@ -112,8 +114,9 @@ class TopologySpec:
         mesh2d          — `data · lanes` devices when available; when
                           jax.devices() cannot cover the shape and no
                           explicit devices were given, devices stays None
-                          and execution falls back to the sequential
-                          replica loop (bit-identical — parallel.mesh2d).
+                          and a CPU backend runs the sequential replica
+                          loop (bit-identical — parallel.mesh2d). On any
+                          other backend too few devices is an error.
         """
         if self.placement == "single":
             return self if self.devices is None else \
@@ -131,11 +134,12 @@ class TopologySpec:
                 dataclasses.replace(self, devices=devs)
         avail = jax.devices()
         if len(avail) < need:
-            if self.placement == "sharded":
+            if self.placement == "sharded" or avail[0].platform != "cpu":
                 raise ValueError(
-                    f"TopologySpec(lanes={self.lanes}) needs {need} "
-                    f"devices, found {len(avail)}")
-            return dataclasses.replace(self, devices=None)  # loop fallback
+                    f"TopologySpec(data={self.data}, lanes={self.lanes}) "
+                    f"needs {need} devices, found {len(avail)} "
+                    f"{avail[0].platform} device(s)")
+            return dataclasses.replace(self, devices=None)  # CPU loop
         return dataclasses.replace(self, devices=tuple(avail[:need]))
 
     @property

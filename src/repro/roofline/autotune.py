@@ -1,8 +1,9 @@
 """Roofline-driven (block_g, block_t) autotuner for the program kernels.
 
 Deterministic and model-driven — no on-device timing sweep. Candidate
-blockings are enumerated over powers of two, filtered by the HwSpec VMEM
-residency budget (double-buffered item slots + state planes must fit), and
+blockings are enumerated over powers of two, filtered by the VMEM
+residency budget (the HwSpec's, capped at the scoped limit the DMA kernel
+requests: item slots + padded, double-buffered state blocks must fit), and
 scored by kernel_model.predict_kernel's predicted wall time; the argmin
 wins with a deterministic tie-break toward larger block_t (state-traffic
 amortization) then larger block_g (fewer DMA issues).
@@ -25,7 +26,8 @@ import math
 from typing import Optional, Tuple
 
 from repro.roofline.analysis import HwSpec, detect_hw, hw_for
-from repro.roofline.kernel_model import predict_kernel, vmem_footprint_bytes
+from repro.roofline.kernel_model import (
+    VMEM_LIMIT_BYTES, predict_kernel, vmem_footprint_bytes)
 
 # the repo-wide default blocking (kernels/frugal_update.py signature)
 DEFAULT_BLOCK_G = 128
@@ -47,11 +49,12 @@ def _tuned(family_base_name: str, layout, hw_name: str,
     if not hw.known:
         return (DEFAULT_BLOCK_G, DEFAULT_BLOCK_T)
     g_eff = max(g * q, 1)
+    vmem_budget = min(hw.vmem_bytes, VMEM_LIMIT_BYTES)
     best = None
     for bg in _pow2_at_most(_BLOCK_G_CANDIDATES, g_eff):
         for bt in _pow2_at_most(_BLOCK_T_CANDIDATES, max(t, 1)):
             if vmem_footprint_bytes(layout, block_g=bg,
-                                    block_t=bt) > hw.vmem_bytes:
+                                    block_t=bt) > vmem_budget:
                 continue
             # keep enough lane blocks to occupy every core
             if math.ceil(g_eff / bg) < hw.cores and bg > _BLOCK_G_CANDIDATES[0]:

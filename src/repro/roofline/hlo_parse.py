@@ -65,22 +65,16 @@ def compiled_cost(compiled) -> Dict[str, float]:
     kernel bandwidth model (roofline/kernel_model.py compares its analytic
     bytes against this).
 
-    cost_analysis() shape varies across jax versions (dict, or a list of
-    per-computation dicts); both are normalized to
+    cost_analysis() returns one dict, read into
     ``{"flops": float, "bytes_accessed": float}``. On XLA:CPU
     ``bytes accessed`` counts every post-fusion dataflow edge (fusion-
     internal tiles included), so treat it as an UPPER bound on HBM traffic,
     not a measurement — the analytic model should come out at or below it.
     """
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    if ca is None:
-        ca = {}
     return {
         "flops": float(ca.get("flops", 0.0)),
-        "bytes_accessed": float(ca.get("bytes accessed",
-                                       ca.get("bytes_accessed", 0.0))),
+        "bytes_accessed": float(ca.get("bytes accessed", 0.0)),
     }
 
 

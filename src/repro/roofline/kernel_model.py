@@ -32,6 +32,9 @@ from repro.roofline.analysis import HwSpec, detect_hw
 
 ITEM_BYTES = 4          # float32 stream items
 WORD_BYTES = 4          # int32/float32 packed state words
+# Scoped VMEM the DMA kernel asks Mosaic for (CompilerParams
+# .vmem_limit_bytes), and so the most the autotuner may plan to fill.
+VMEM_LIMIT_BYTES = 32 * 2**20
 
 
 def kernel_bytes_per_item(layout, q: int = 1, *,
@@ -57,10 +60,13 @@ def kernel_bytes_total(g: int, t: int, q: int, layout, *,
 
 
 def vmem_footprint_bytes(layout, *, block_g: int, block_t: int) -> int:
-    """VMEM bytes one grid cell keeps resident: 2 double-buffer item slots
-    + state words in/out + the seed/meta scalars (negligible, counted)."""
+    """VMEM bytes one grid cell of the DMA kernel keeps resident: the two
+    item slots, then the pipelined [1, block_g] blocks (quantile, state
+    words in and out), each double-buffered and padded to the 8 sublanes
+    of a 32-bit VMEM tile, then the seed/meta scalars."""
     items = 2 * block_t * block_g * ITEM_BYTES
-    state = 2 * layout.num_words * block_g * WORD_BYTES
+    blocks = 1 + 2 * layout.num_words
+    state = 2 * blocks * 8 * block_g * WORD_BYTES
     return items + state + 256
 
 

@@ -218,7 +218,6 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.parallel.compression import compressed_psum, ef_init
-from repro.parallel.mesh2d import shard_map_compat
 
 mesh = Mesh(np.asarray(jax.devices()[:8]), ("data",))
 rng = np.random.default_rng(0)
@@ -228,8 +227,8 @@ def body(g, ef):
     avg, ef2 = compressed_psum({"g": g[0]}, {"g": ef[0]}, "data")
     return avg["g"][None], ef2["g"][None]
 
-f = shard_map_compat(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                     out_specs=(P("data"), P("data")))
+f = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                  out_specs=(P("data"), P("data")), check_vma=False)
 ef = jnp.zeros((8, 64))
 avg, ef = f(g_global, ef)
 want = jnp.mean(g_global, axis=0)

@@ -140,47 +140,54 @@ def test_kernel_nan_padding_is_noop():
                 err_msg=f"{prog.family} {f} perturbed by NaN ticks")
 
 
-def test_scatter_kernel_matches_jnp_sparse_every_family():
-    """The event-round scatter kernel (gather→tick→scatter against resident
-    state, input_output_aliases) must replay the jnp sparse path bit-for-bit
-    for every registered program: multi-block grids, non-zero g_offset,
-    mask-0 NaN pad slots, and K not a multiple of block_k (internal pad)."""
+@pytest.mark.parametrize("family",
+                         [p.family for p in program_mod.test_instances()])
+@pytest.mark.parametrize("donate", [False, True])
+def test_sparse_round_matches_dense_lane_tick(family, donate):
+    """The O(events) sparse round (gather→tick→scatter, functional and
+    donated) must replay the dense per-lane-clock round over all L lanes
+    bit-for-bit: non-zero g_offset, several rounds, and mask-0 NaN pad
+    slots on event-free lanes."""
+    from repro.api.fleet import _lane_tick
     from repro.kernels import ops as kernel_ops
 
+    prog = next(p for p in program_mod.test_instances()
+                if p.family == family)
+    base = program_mod.family_base(prog.kernel_family)
+    scalars = tuple(jnp.asarray(v, jnp.int32) for v in prog.scalar_values())
     L, g_off = 96, 1000
     rng = np.random.default_rng(31)
     m0 = jnp.asarray(rng.integers(0, 200, L), jnp.float32)
     qv = jnp.asarray(rng.choice([0.1, 0.5, 0.9], L), jnp.float32)
-    for prog in program_mod.test_instances():
-        planes_j = _init_planes(prog, m0)
-        planes_p = tuple(jnp.array(p) for p in planes_j)
-        ticks_j = jnp.zeros((L,), jnp.int32)
-        ticks_p = jnp.zeros((L,), jnp.int32)
-        for r, k in enumerate((1, 40, 96, 70)):
-            lanes = np.sort(rng.choice(L, k, replace=False)).astype(np.int32)
-            vals = rng.integers(0, 200, k).astype(np.float32)
-            mask = np.ones(k, np.int32)
-            if k < L:   # explicit mask-0 pad on an event-free lane
-                pad = next(i for i in range(L)
-                           if i not in set(lanes.tolist()))
-                lanes = np.append(lanes, np.int32(pad))
-                vals = np.append(vals, np.float32(np.nan))
-                mask = np.append(mask, np.int32(0))
-            planes_j, ticks_j = kernel_ops.frugal_update_sparse(
-                lanes, vals, mask, planes_j, ticks_j, qv, SEED,
-                program=prog, g_offset=g_off)
-            planes_p, ticks_p = kernel_ops.frugal_update_sparse(
-                lanes, vals, mask, planes_p, ticks_p, qv, SEED,
-                program=prog, g_offset=g_off, block_k=32, interpret=True)
-            for f, a, b in zip(prog.layout.plane_fields, planes_j,
-                               planes_p):
-                np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b),
-                    err_msg=f"{prog.family} plane {f!r} diverges from jnp "
-                            f"at round {r}")
+    planes_d = _init_planes(prog, m0)
+    planes_s = tuple(jnp.array(p) for p in planes_d)
+    ticks_d = jnp.zeros((L,), jnp.int32)
+    ticks_s = jnp.zeros((L,), jnp.int32)
+    for r, k in enumerate((1, 40, 96, 70)):
+        lanes = np.sort(rng.choice(L, k, replace=False)).astype(np.int32)
+        vals = rng.integers(0, 200, k).astype(np.float32)
+        items = np.full(L, np.nan, np.float32)
+        items[lanes] = vals
+        mask = np.ones(k, np.int32)
+        if k < L:   # explicit mask-0 pad on an event-free lane
+            pad = next(i for i in range(L) if i not in set(lanes.tolist()))
+            lanes = np.append(lanes, np.int32(pad))
+            vals = np.append(vals, np.float32(np.nan))
+            mask = np.append(mask, np.int32(0))
+        planes_d = _lane_tick(planes_d, ticks_d, qv, jnp.asarray(items),
+                              jnp.int32(SEED), g_off, scalars, program=base)
+        ticks_d = ticks_d + jnp.asarray(~np.isnan(items), jnp.int32)
+        planes_s, ticks_s = kernel_ops.frugal_update_sparse(
+            lanes, vals, mask, planes_s, ticks_s, qv, SEED, program=prog,
+            g_offset=g_off, donate=donate)
+        for f, a, b in zip(prog.layout.plane_fields, planes_d, planes_s):
             np.testing.assert_array_equal(
-                np.asarray(ticks_j), np.asarray(ticks_p),
-                err_msg=f"{prog.family} lane clocks diverge at round {r}")
+                np.asarray(a), np.asarray(b),
+                err_msg=f"{family} plane {f!r} diverges from the dense "
+                        f"round at round {r}")
+        np.testing.assert_array_equal(
+            np.asarray(ticks_d), np.asarray(ticks_s),
+            err_msg=f"{family} lane clocks diverge at round {r}")
 
 
 def test_kernel_per_lane_quantiles():

@@ -104,7 +104,7 @@ _cpu_only = pytest.mark.skipif(
 @_cpu_only
 def test_explicit_compiled_request_off_accelerator_refuses(program):
     """interpret=False off tpu/gpu: a ValueError naming the auto entry
-    point, for the dense AND the sparse seam — never a Mosaic crash."""
+    point — never a Mosaic crash."""
     t, g = 8, 4
     items, m = _mk(t, g)
     planes = _init_planes(program, m)
@@ -112,38 +112,31 @@ def test_explicit_compiled_request_off_accelerator_refuses(program):
     with pytest.raises(ValueError, match="frugal_update_auto"):
         frugal_update_blocked(items, planes, qv, SEED, program=program,
                               interpret=False)
-    ticks = jnp.zeros((g,), jnp.int32)
-    with pytest.raises(ValueError, match="frugal_update_auto"):
-        frugal_update_sparse(jnp.arange(g), jnp.ones(g),
-                             jnp.ones(g, jnp.int32), planes, ticks, qv,
-                             SEED, program=program, interpret=False)
 
 
 @_cpu_only
 def test_default_dispatch_runs_and_matches_interpret_kernel(program):
-    """interpret=None picks a WORKING lowering per platform: the sparse
-    seam routes to the jitted scatter pair on CPU (the old seam only
-    spared None, so this pins the fallback arm), bit-identical to the
-    interpret-mode scatter kernel; the dense auto facade runs the scan."""
+    """The default dispatch runs on CPU: the dense auto facade takes the
+    jitted scan, bit-identical to the interpret-mode DMA kernel, and the
+    sparse seam runs the jitted scatter pair."""
     g = 5
     _, m = _mk(1, g)
     planes = _init_planes(program, m)
-    ticks = jnp.zeros((g,), jnp.int32)
     qv = jnp.full((g,), 0.5, jnp.float32)
+    items, _ = _mk(16, g)
+    out = frugal_update_auto(items, planes, qv, seed=SEED, program=program)
+    out_k = frugal_update_blocked(items, planes, qv, SEED, program=program,
+                                  interpret=True, kernel="dma")
+    for f, a, b in zip(program.layout.plane_fields, out, out_k):
+        np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b),
+            err_msg=f"{program.family}: {f} diverges between the default "
+                    "dispatch and the interpret DMA kernel")
+    ticks = jnp.zeros((g,), jnp.int32)
     lanes = jnp.arange(4, dtype=jnp.int32)
     vals = jnp.asarray([5.0, 50.0, 500.0, 5000.0], jnp.float32)
     mask = jnp.ones((4,), jnp.int32)
-    pl_none, tk_none = frugal_update_sparse(
+    pl_sp, tk_sp = frugal_update_sparse(
         lanes, vals, mask, planes, ticks, qv, SEED, program=program)
-    pl_int, tk_int = frugal_update_sparse(
-        lanes, vals, mask, planes, ticks, qv, SEED, program=program,
-        interpret=True)
-    for f, a, b in zip(program.layout.plane_fields, pl_none, pl_int):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=f"{program.family}: {f} diverges between the None "
-                    "dispatch and the interpret scatter kernel")
-    np.testing.assert_array_equal(np.asarray(tk_none), np.asarray(tk_int))
-    items, _ = _mk(16, g)
-    out = frugal_update_auto(items, planes, qv, seed=SEED, program=program)
-    assert all(x.shape == (g,) for x in out)
+    assert all(x.shape == (g,) for x in pl_sp)
+    np.testing.assert_array_equal(np.asarray(tk_sp), [1, 1, 1, 1, 0])
