@@ -208,6 +208,13 @@ def _program_kernel_gpu(meta_ref, q_ref, items_ref, *state_refs, program,
         r[...] = w
 
 
+def _kernel_name(program, lowering: str) -> str:
+    """The `pallas_call` name: XLA names the kernel's custom-call op after
+    it, so a device trace says which kernel and family ran (on a TPU the
+    dense DMA kernel of `2u` is `%frugal_2u_dma.<n>`)."""
+    return f"frugal_{program.family.replace('-', '_')}_{lowering}"
+
+
 def _seed_operand(seed, t_offset, g_offset, scalars=()) -> Array:
     """[3 + n] int32 scalar-prefetch operand: (counter seed, stream tick
     offset, fleet-global lane offset, *program scalar slots)."""
@@ -265,6 +272,7 @@ def frugal_program_pallas(
                    for dt in layout.word_dtypes],
         compiler_params=_compiler_params(),
         interpret=interpret,
+        name=_kernel_name(program, "grid"),
     )(_seed_operand(seed, t_offset, g_offset, scalars), quantile[None, :],
       items, *[w[None, :] for w in words])
     return tuple(o[0] for o in outs)
@@ -324,6 +332,7 @@ def frugal_program_pallas_dma(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=kernel_model.VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name=_kernel_name(program, "dma"),
     )(_seed_operand(seed, t_offset, g_offset, scalars), quantile[None, :],
       items, *[w[None, :] for w in words])
     return tuple(o[0] for o in outs)
@@ -368,6 +377,7 @@ def frugal_program_pallas_gpu(
         out_shape=[jax.ShapeDtypeStruct((1, g), dt)
                    for dt in layout.word_dtypes],
         interpret=interpret,
+        name=_kernel_name(program, "gpu"),
     )(_seed_operand(seed, t_offset, g_offset, scalars)[None, :],
       quantile[None, :], items, *[w[None, :] for w in words])
     return tuple(o[0] for o in outs)

@@ -19,14 +19,20 @@ readers. Blocking per chunk is deliberate: it gives honest per-chunk
 latency numbers and a real publication point — an unbounded dispatch queue
 would "publish" fleets whose device work hasn't happened yet.
 
-Telemetry (optional, duck-typed): items/chunks counters, a chunks-in-
-flight gauge, and per-chunk apply latency into the `ingest_chunk_ms`
-histogram.
+Spans (`telemetry.span`, recorded while a profiler capture runs), each
+keyed by the chunk's number: `ingest.stage` (the put-ahead thread's
+`transfer` of one chunk; `jax.device_put` returns once the copy is under
+way), `ingest.wait_staged` (the apply loop waiting
+for the next staged chunk), `ingest.apply` (`fleet.ingest` plus the
+wait on its result) and its child `ingest.block` (that wait alone: the
+device working on the chunk).
+
+Telemetry (optional, duck-typed): items/chunks counters and each
+`ingest.apply` span's duration into the `ingest_chunk_ms` histogram.
 """
 from __future__ import annotations
 
-import threading
-import time
+import itertools
 from typing import Callable, Iterable, Optional
 
 import jax
@@ -34,6 +40,8 @@ import numpy as np
 
 from repro.api.fleet import QuantileFleet
 from repro.data.pipeline import prefetch_to_device
+
+from .telemetry import span
 
 
 def _block_on(fleet: QuantileFleet) -> None:
@@ -56,6 +64,7 @@ class IngestPipeline:
         self.depth = int(depth)
         self.telemetry = telemetry
         self._transfer = transfer
+        self._applied = 0
 
     def run(self, fleet: QuantileFleet, chunks: Iterable,
             on_chunk: Optional[Callable] = None) -> QuantileFleet:
@@ -63,42 +72,38 @@ class IngestPipeline:
         fleet. `on_chunk(new_fleet, n_items)` fires after each chunk's
         device work completes — the server's publication hook."""
         tel = self.telemetry
-        # in-flight = staged on device but not yet applied; the staging
-        # thread increments (inside `transfer`), the apply loop decrements,
-        # so the gauge really tracks the put-ahead occupancy 0..depth+1.
-        in_flight = [0]
-        lock = threading.Lock()
-
-        def bump(d: int):
-            with lock:
-                in_flight[0] += d
-                tel.gauge("chunks_in_flight", in_flight[0])
-
+        # Chunk numbers count every chunk this pipeline has applied; staging
+        # runs ahead in its own thread but in the same order.
+        first = self._applied
         if self._transfer is None:
             staged = iter(chunks)
         else:
             base = self._transfer
+            numbers = itertools.count(first)
 
             def transfer(x):
-                y = base(x)
-                if tel is not None:
-                    bump(+1)
-                return y
+                with span("ingest.stage", key=next(numbers)):
+                    return base(x)
 
             staged = prefetch_to_device(iter(chunks), depth=self.depth,
                                         transfer=transfer)
-        for chunk in staged:
-            t0 = time.perf_counter()
+        for k in itertools.count(first):
+            with span("ingest.wait_staged", key=k) as waited:
+                chunk = next(staged, None)
+                if chunk is None:
+                    waited.drop()
+            if chunk is None:
+                break
             n = int(np.shape(chunk)[0])
-            fleet = fleet.ingest(chunk)
-            _block_on(fleet)
+            with span("ingest.apply", key=k) as applied:
+                fleet = fleet.ingest(chunk)
+                with span("ingest.block"):
+                    _block_on(fleet)
+            self._applied = k + 1
             if tel is not None:
-                tel.observe_ms("ingest_chunk_ms",
-                               (time.perf_counter() - t0) * 1e3)
+                tel.observe_ms("ingest_chunk_ms", applied.ms)
                 tel.count("items_ingested", n)
                 tel.count("chunks_ingested")
-                if self._transfer is not None:
-                    bump(-1)
             if on_chunk is not None:
                 on_chunk(fleet, n)
         return fleet

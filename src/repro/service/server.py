@@ -23,8 +23,8 @@ and re-raised at `join()` — a dying source never deadlocks a reader.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
-import time
 from typing import Dict, Iterable, Optional, Sequence
 
 import numpy as np
@@ -34,7 +34,7 @@ from repro.api.spec import FleetSpec
 
 from .pipeline import IngestPipeline
 from .snapshot import Snapshot
-from .telemetry import QUERIES_SERVED, Telemetry
+from .telemetry import Telemetry, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +85,7 @@ class StreamingService:
                                        telemetry=self.telemetry)
         self._thread: Optional[threading.Thread] = None
         self._ingest_error: Optional[BaseException] = None
+        self._reads = itertools.count()
 
     # ------------------------------------------------------------- versions
     @property
@@ -153,17 +154,18 @@ class StreamingService:
         """Answer one quantile read for `tenant` from a fresh snapshot:
         [G, Q] (or `quantile=`'s [G] column), DP-gated by the tenant's
         policy. Raises KeyError for an unregistered tenant — an unknown
-        reader must never see even a noised release."""
+        reader must never see even a noised release. The read is the span
+        `query`, keyed by its number, with `query.snapshot` and (untrusted
+        tenants) `query.dp_release` inside it."""
         policy = self._tenants[tenant]
-        t0 = time.perf_counter()
-        snap = self.snapshot()
-        if policy.trusted:
-            out = snap.estimate(quantile)
-        else:
-            out = snap.estimate_dp(policy.epsilon, quantile)
-        self.telemetry.observe_ms("query_ms",
-                                  (time.perf_counter() - t0) * 1e3)
-        self.telemetry.count(QUERIES_SERVED)
+        with span("query", key=next(self._reads)) as read:
+            snap = self.snapshot()
+            if policy.trusted:
+                out = snap.estimate(quantile)
+            else:
+                out = snap.estimate_dp(policy.epsilon, quantile)
+        self.telemetry.observe_ms("query_ms", read.ms)
+        self.telemetry.count("queries_served")
         return out
 
     # ---------------------------------------------------------------- health
@@ -181,6 +183,6 @@ class StreamingService:
 
     # ------------------------------------------------------------ telemetry
     def stats(self) -> Dict[str, object]:
-        """Coherent observability readout (counters, gauges, latency
-        quantiles from the frugal histogram lanes)."""
+        """Coherent observability readout (counters, latency quantiles
+        from the frugal histogram lanes)."""
         return self.telemetry.snapshot()

@@ -38,6 +38,8 @@ from repro.api.fleet import QuantileFleet
 from repro.core.program import LaneProgram, make_program
 from repro.resilience import chaos
 
+from .telemetry import span
+
 
 @dataclasses.dataclass(frozen=True)
 class Snapshot:
@@ -62,12 +64,14 @@ class Snapshot:
                 telemetry=None) -> "Snapshot":
         """Copy-on-query capture of `fleet` (the caller has already pinned
         which version). `telemetry` (optional, duck-typed `.count`) records
-        stall counts; the server times the full query round-trip itself."""
+        stall counts; the server times the full query round-trip itself.
+        The capture is the span `query.snapshot`."""
         try:
-            # The worst place for a reader to die: version pinned, gather
-            # not yet done. chaos injects QueryStalled here.
-            chaos.on_query_event()
-            m_planes, t_next, seed, lanes = fleet.query_view()
+            with span("query.snapshot"):
+                # The worst place for a reader to die: version pinned,
+                # gather not yet done. chaos injects QueryStalled here.
+                chaos.on_query_event()
+                m_planes, t_next, seed, lanes = fleet.query_view()
         except chaos.QueryStalled:
             if telemetry is not None:
                 telemetry.count("queries_stalled")
@@ -113,14 +117,17 @@ class Snapshot:
         question, same noised answer — replayable for audit).
 
         A fleet already running `2u-dp` releases through its OWN calibrated
-        noise; stacking a second draw would double-spend the budget."""
+        noise; stacking a second draw would double-spend the budget.
+        The second release is the span `query.dp_release`."""
         if self.program.family == "2u-dp":
             return self.estimate(quantile)
         base = self._released(self.program)
-        dp = make_program("2u-dp", epsilon=float(epsilon))
-        plane = np.asarray(dp.run_query(
-            (base,), t_next=self.t_next, seed=self.seed,
-            lanes=self.lanes)).reshape(self.num_groups, self.num_quantiles)
+        with span("query.dp_release"):
+            dp = make_program("2u-dp", epsilon=float(epsilon))
+            plane = np.asarray(dp.run_query(
+                (base,), t_next=self.t_next, seed=self.seed,
+                lanes=self.lanes)).reshape(self.num_groups,
+                                           self.num_quantiles)
         if quantile is None:
             return plane
         return plane[:, self.quantiles.index(float(quantile))]

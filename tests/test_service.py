@@ -15,8 +15,11 @@ The load-bearing guarantees (DESIGN.md §14):
     source draw with consumer compute (proven by event ordering, not
     wall-clock), yields bit-identical values, and relays source errors;
   * DP tenant gating     — untrusted tenants read only the noised release,
-    deterministic at a cursor; unknown tenants read nothing.
+    deterministic at a cursor; unknown tenants read nothing;
+  * spans                — recorded only inside a profiler capture, into
+    the log and the profile's host plane, the newest capture's alone.
 """
+import glob
 import os
 import threading
 import time
@@ -31,7 +34,8 @@ from repro.data.pipeline import DataConfig, SyntheticCorpus, \
     prefetch_to_device
 from repro.resilience import FaultPlan, QueryStalled, chaos
 from repro.service import (IngestPipeline, Snapshot, StreamingService,
-                           Telemetry, TenantPolicy, runtime_metadata)
+                           Telemetry, TenantPolicy, recorded_spans,
+                           runtime_metadata, span, telemetry)
 
 SEEDS = tuple(int(s) for s in os.environ.get("CHAOS_SEEDS", "0").split(","))
 
@@ -327,6 +331,164 @@ def test_telemetry_histogram_is_replayable():
         return tel.latency_quantiles()
 
     assert feed() == feed()
+
+
+# ------------------------------------------------------------------- spans
+INGEST_SPANS = ("ingest.stage", "ingest.wait_staged", "ingest.apply",
+                "ingest.block")
+
+
+class _capture:
+    """A CPU `jax.profiler` capture into `tmp`; `.host` is the set of event
+    names on the capture's `/host:` planes after it."""
+
+    def __init__(self, tmp):
+        self.dir = str(tmp)
+
+    def __enter__(self):
+        jax.profiler.start_trace(self.dir)
+        return self
+
+    def __exit__(self, *exc):
+        jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(self.dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        self.host = {ev.name
+                     for plane in jax.profiler.ProfileData.from_file(
+                         path).planes if plane.name.startswith("/host:")
+                     for line in plane.lines for ev in line.events}
+
+
+def _names(log):
+    return [r.name for r in log.spans]
+
+
+def test_outside_a_capture_no_span_is_recorded_and_histograms_fill(
+        tmp_path):
+    with _capture(tmp_path / "before"):
+        with span("marker"):
+            pass
+    assert _names(recorded_spans()) == ["marker"]
+    tel = Telemetry()
+    svc = StreamingService(_spec("fused"), seed=0, telemetry=tel,
+                           tenants=[TenantPolicy("ext", epsilon=0.5)])
+    svc.ingest_stream(_chunks(n=3))
+    svc.query()
+    svc.query(tenant="ext")
+    with span("outside") as s:
+        pass
+    assert s.ms >= 0.0
+    # The log still holds the last capture's spans and nothing since.
+    assert _names(recorded_spans()) == ["marker"]
+    lat = tel.latency_quantiles()
+    assert np.isfinite(lat["ingest_chunk_ms"]["p50"])
+    assert np.isfinite(lat["query_ms"]["p50"])
+    assert tel.counters()["chunks_ingested"] == 3
+    assert set(tel.snapshot()) == {"counters", "latency_ms"}
+
+
+def test_pipeline_spans_in_a_capture(tmp_path):
+    pipe = IngestPipeline(depth=1, telemetry=Telemetry())
+    with _capture(tmp_path) as cap:
+        pipe.run(QuantileFleet.create(_spec("fused"), seed=0), _chunks(n=4))
+    log = recorded_spans()
+    assert log.dropped == 0
+    by = {n: [r for r in log.spans if r.name == n] for n in INGEST_SPANS}
+    for name, recs in by.items():
+        assert sorted(r.key for r in recs) == [0, 1, 2, 3], name
+        assert name in cap.host
+    apply = {r.span_id: r for r in by["ingest.apply"]}
+    for blk in by["ingest.block"]:
+        parent = apply[blk.parent_id]
+        assert parent.key == blk.key
+        assert parent.start_ns <= blk.start_ns <= blk.end_ns <= parent.end_ns
+    assert {r.thread for r in by["ingest.stage"]} == {"prefetch_to_device"}
+    assert all(r.parent_id is None for r in by["ingest.wait_staged"])
+    # A second run of the same pipeline numbers its chunks on from 4.
+    with _capture(tmp_path / "again"):
+        pipe.run(QuantileFleet.create(_spec("fused"), seed=0), _chunks(n=1))
+    assert {r.key for r in recorded_spans().spans} == {4}
+
+
+def test_read_spans_share_one_read_number(tmp_path):
+    svc = StreamingService(_spec("fused"), seed=0,
+                           tenants=[TenantPolicy("ext", epsilon=0.5)])
+    svc.ingest(_chunks(n=1)[0])
+    with _capture(tmp_path) as cap:
+        svc.query()
+        svc.query(tenant="ext", quantile=0.5)
+    log = recorded_spans()
+    reads = {r.key: r for r in log.spans if r.name == "query"}
+    assert len(reads) == 2
+    trusted, dp = sorted(reads)
+    kids = {k: sorted(r.name for r in log.spans if r.parent_id ==
+                      reads[k].span_id) for k in reads}
+    assert kids == {trusted: ["query.snapshot"],
+                    dp: ["query.dp_release", "query.snapshot"]}
+    assert all(r.key in reads for r in log.spans)
+    assert {"query", "query.snapshot", "query.dp_release"} <= cap.host
+
+
+def test_a_second_capture_holds_only_its_own_spans(tmp_path):
+    with _capture(tmp_path / "one"):
+        with span("first", key=1):
+            with span("first.child"):
+                pass
+    log = recorded_spans()
+    assert _names(log) == ["first.child", "first"]
+    assert log.spans[0].key == 1                 # taken from the parent
+    # Back to back: no span runs between the two captures.
+    with _capture(tmp_path / "two"):
+        with span("second"):
+            pass
+    assert _names(recorded_spans()) == ["second"]
+
+
+def test_spans_from_many_threads_keep_their_own_parents(tmp_path):
+    """Threads recording at once lose no record and never take another
+    thread's open span as a parent."""
+    import sys
+
+    n_threads, per = 2 * (os.cpu_count() or 1) + 2, 50
+
+    def work(i):
+        for j in range(per):
+            with span("outer", key=i * per + j):
+                with span("inner"):
+                    pass
+
+    threads = [threading.Thread(target=work, args=(i,), name=f"w{i}")
+               for i in range(n_threads)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with _capture(tmp_path):
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    log = recorded_spans()
+    outer = {r.span_id: r for r in log.spans if r.name == "outer"}
+    inner = [r for r in log.spans if r.name == "inner"]
+    assert len(outer) == len(inner) == n_threads * per and log.dropped == 0
+    assert len({r.span_id for r in log.spans}) == 2 * n_threads * per
+    for r in inner:
+        parent = outer[r.parent_id]
+        assert (parent.thread, parent.key) == (r.thread, r.key)
+
+
+def test_span_log_is_bounded_and_counts_what_it_dropped(tmp_path,
+                                                         monkeypatch):
+    monkeypatch.setattr(telemetry, "SPAN_LOG_LIMIT", 3)
+    with _capture(tmp_path):
+        for i in range(5):
+            with span("tick", key=i):
+                pass
+    log = recorded_spans()
+    assert [r.key for r in log.spans] == [0, 1, 2] and log.dropped == 2
 
 
 def test_slo_fleet_threads_telemetry_and_snapshot_reads():
