@@ -132,6 +132,19 @@ def _check_sparse_lanes(lanes, items, mask):
             "order)")
 
 
+def quantile_column(view, num_quantiles: int, qi: int):
+    """A query view `(m_planes, t_next, seed, lanes)` cut to one tracked
+    target: lane g·Q + qi holds group g's target qi, so the column is every
+    Q-th lane from qi (and every Q-th tick of a per-lane clock). Every
+    program's query is per lane, so its release over the column is the
+    column of its release over all lanes, bit for bit."""
+    m_planes, t_next, seed, lanes = view
+    q = num_quantiles
+    if np.ndim(t_next) == 1:
+        t_next = t_next[qi::q]
+    return tuple(p[qi::q] for p in m_planes), t_next, seed, lanes[qi::q]
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class QuantileFleet:
@@ -631,14 +644,20 @@ class QuantileFleet:
         cursor, not of sketch state), and the 2u-dp rule releases
         Laplace-noised values keyed deterministically on the cursor. Only
         the layout's query planes are gathered — a windowed sharded fleet
-        transfers its two m planes, never the step/sign words."""
+        transfers its two m planes, never the step/sign words — and with
+        `quantile=` the query runs over that target's lanes alone."""
         prog = self.spec.program
-        m_planes, t_next, seed, lanes = self.query_view()
-        m = prog.run_query(m_planes, t_next=t_next, seed=seed, lanes=lanes)
-        plane = np.asarray(m).reshape(self.num_groups, self.num_quantiles)
-        if quantile is None:
-            return plane
-        return plane[:, self.spec.quantiles.index(float(quantile))]
+        view = self.query_view()
+        if quantile is not None:
+            view = quantile_column(
+                view, self.num_quantiles,
+                self.spec.quantiles.index(float(quantile)))
+        m_planes, t_next, seed, lanes = view
+        m = np.asarray(prog.run_query(m_planes, t_next=t_next, seed=seed,
+                                      lanes=lanes))
+        if quantile is not None:
+            return m
+        return m.reshape(self.num_groups, self.num_quantiles)
 
     # -------------------------------------------------------- serialization
     def checkpoint_state(self) -> dict:

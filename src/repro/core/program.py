@@ -76,6 +76,9 @@ Array = jax.Array
 # Salt for the DP reporting-noise stream: keeps query-time draws disjoint
 # from every ingest-time uniform (which key on the raw seed).
 _DP_SALT = int(np.int32(np.uint32(0x5DEECE66).view(np.int32)))
+# Lanes per pass of the DP release: 64K lanes keep its 512 KiB float64
+# temporaries in a core's cache.
+_DP_BLOCK = 1 << 16
 
 
 # Plane-invariant domains resilience.health knows how to check. Every
@@ -331,20 +334,30 @@ def _query_window(prog, m_planes, t_next, seed, lanes):
 def _query_dp(prog, m_planes, t_next, seed, lanes):
     """Laplace-noised reporting: estimate + Lap(1/epsilon), with the noise
     a pure function of (seed ^ salt, t_next, lane). Same stream position ->
-    same released value, on every backend."""
+    same released value, on every backend. Host numpy alone: the inputs are
+    host copies, so the release makes no JAX call and no transfer. It runs
+    `_DP_BLOCK` lanes at a time, so its float64 temporaries stay in cache
+    rather than streaming through host memory that ingest staging shares;
+    every lane's arithmetic is the same, so the bits are too."""
     if seed is None or t_next is None or lanes is None:
         raise ValueError(
             "2u-dp: noised reporting needs the stream cursor (seed, t_next, "
             "lane ids) — read through repro.api.QuantileFleet")
-    u = np.asarray(crng.counter_uniform(
-        crng.wrap_i32(int(seed) ^ _DP_SALT),
-        jnp.asarray(t_next, jnp.int32),
-        jnp.asarray(lanes, jnp.int32)), np.float64)
-    centered = u - 0.5
+    m, t, lanes = np.asarray(m_planes[0]), np.asarray(t_next), \
+        np.asarray(lanes)
+    key = int(seed) ^ _DP_SALT
     scale = 1.0 / float(prog.dp_epsilon)
-    noise = -scale * np.sign(centered) * np.log(
-        np.maximum(1.0 - 2.0 * np.abs(centered), np.finfo(np.float64).tiny))
-    return (np.asarray(m_planes[0], np.float64) + noise).astype(np.float32)
+    out = np.empty(m.shape, np.float32)
+    for i in range(0, m.shape[0], _DP_BLOCK):
+        b = slice(i, i + _DP_BLOCK)
+        u = crng.counter_uniform_host(key, t[b] if t.ndim else t,
+                                      lanes[b]).astype(np.float64)
+        centered = u - 0.5
+        noise = -scale * np.sign(centered) * np.log(
+            np.maximum(1.0 - 2.0 * np.abs(centered),
+                       np.finfo(np.float64).tiny))
+        out[b] = (np.asarray(m[b], np.float64) + noise).astype(np.float32)
+    return out
 
 
 # ------------------------------------------------------------ trace functions

@@ -37,6 +37,9 @@ _M2 = np.int32(np.uint32(0xC2B2AE35).view(np.int32))
 _C_TICK = np.int32(np.uint32(0x9E3779B9).view(np.int32))   # golden ratio
 _C_GROUP = np.int32(np.uint32(0x85EBCA77).view(np.int32))
 _EXP_ONE = np.int32(0x3F800000)                            # f32 bits of 1.0
+# The same constants as uint32, for the host twin.
+_M1_U, _M2_U, _C_TICK_U, _C_GROUP_U, _EXP_ONE_U = (
+    c.view(np.uint32) for c in (_M1, _M2, _C_TICK, _C_GROUP, _EXP_ONE))
 
 
 def _fmix32(h: Array) -> Array:
@@ -67,6 +70,33 @@ def counter_uniform(seed, t, g) -> Array:
     bits = counter_bits(seed, t, g)
     mant = jax.lax.shift_right_logical(bits, 9) | _EXP_ONE
     return jax.lax.bitcast_convert_type(mant, jnp.float32) - 1.0
+
+
+def _u32_host(x) -> np.ndarray:
+    """Integer (array) folded to uint32 two's complement: `wrap_i32`'s
+    wrap, so a seed, tick or lane past int32 hashes as the device's does."""
+    if isinstance(x, (int, np.integer)):
+        return np.uint32(int(x) & 0xFFFFFFFF)
+    return np.asarray(x).astype(np.uint32, copy=False)   # C cast: mod 2^32
+
+
+def _fmix32_host(h: np.ndarray) -> np.ndarray:
+    """`_fmix32` in numpy uint32: wrapping multiplies, logical shifts."""
+    h = h ^ (h >> np.uint32(16))
+    h = h * _M1_U
+    h = h ^ (h >> np.uint32(13))
+    h = h * _M2_U
+    return h ^ (h >> np.uint32(16))
+
+
+def counter_uniform_host(seed, t, g) -> np.ndarray:
+    """`counter_uniform` on the host, in numpy: bit-identical float32
+    uniforms with no JAX call, for reads that already hold host data."""
+    with np.errstate(over="ignore"):    # uint32 scalar multiplies wrap
+        h = _fmix32_host(_u32_host(seed) + _u32_host(t) * _C_TICK_U)
+        h = _fmix32_host(h + _u32_host(g) * _C_GROUP_U)
+    mant = (h >> np.uint32(9)) | _EXP_ONE_U
+    return np.asarray(mant).view(np.float32) - np.float32(1.0)
 
 
 def wrap_i32(n: int) -> int:

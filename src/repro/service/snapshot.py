@@ -34,7 +34,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from repro.api.fleet import QuantileFleet
+from repro.api.fleet import QuantileFleet, quantile_column
 from repro.core.program import LaneProgram, make_program
 from repro.resilience import chaos
 
@@ -93,20 +93,29 @@ class Snapshot:
                              "count; read t_next directly")
         return int(t)
 
-    def _released(self, program: LaneProgram) -> np.ndarray:
-        return np.asarray(program.run_query(
-            self.m_planes, t_next=self.t_next, seed=self.seed,
-            lanes=self.lanes))
+    def _view(self, quantile: Optional[float]):
+        """The cursor's query view; with `quantile=` only that target's
+        lanes (`quantile_column`)."""
+        view = (self.m_planes, self.t_next, self.seed, self.lanes)
+        if quantile is None:
+            return view
+        return quantile_column(view, self.num_quantiles,
+                               self.quantiles.index(float(quantile)))
+
+    def _shaped(self, released, quantile: Optional[float]) -> np.ndarray:
+        released = np.asarray(released)
+        if quantile is not None:
+            return released
+        return released.reshape(self.num_groups, self.num_quantiles)
 
     def estimate(self, quantile: Optional[float] = None) -> np.ndarray:
         """[G, Q] estimates via the program's own query (the trusted read:
         for a `2u-dp` program this is already the noised release); with
-        `quantile=` one tracked target's [G] column."""
-        plane = self._released(self.program).reshape(
-            self.num_groups, self.num_quantiles)
-        if quantile is None:
-            return plane
-        return plane[:, self.quantiles.index(float(quantile))]
+        `quantile=` one tracked target's [G] column, queried over that
+        target's lanes alone."""
+        m_planes, t_next, seed, lanes = self._view(quantile)
+        return self._shaped(self.program.run_query(
+            m_planes, t_next=t_next, seed=seed, lanes=lanes), quantile)
 
     def estimate_dp(self, epsilon: float,
                     quantile: Optional[float] = None) -> np.ndarray:
@@ -118,16 +127,16 @@ class Snapshot:
 
         A fleet already running `2u-dp` releases through its OWN calibrated
         noise; stacking a second draw would double-spend the budget.
-        The second release is the span `query.dp_release`."""
+        The second release is the span `query.dp_release`: host numpy over
+        the answered lanes only (one [G] column with `quantile=`), with no
+        JAX call and no transfer."""
         if self.program.family == "2u-dp":
             return self.estimate(quantile)
-        base = self._released(self.program)
+        m_planes, t_next, seed, lanes = self._view(quantile)
+        base = self.program.run_query(m_planes, t_next=t_next, seed=seed,
+                                      lanes=lanes)
         with span("query.dp_release"):
             dp = make_program("2u-dp", epsilon=float(epsilon))
-            plane = np.asarray(dp.run_query(
-                (base,), t_next=self.t_next, seed=self.seed,
-                lanes=self.lanes)).reshape(self.num_groups,
-                                           self.num_quantiles)
-        if quantile is None:
-            return plane
-        return plane[:, self.quantiles.index(float(quantile))]
+            released = dp.run_query((base,), t_next=t_next, seed=seed,
+                                    lanes=lanes)
+        return self._shaped(released, quantile)

@@ -216,6 +216,33 @@ def test_counter_uniform_statistics():
     assert abs(lag1) < 4 / np.sqrt(n)
 
 
+_INT32_MIN, _INT32_MAX = -2 ** 31, 2 ** 31 - 1
+_N_LANES = 1024
+
+
+@pytest.mark.parametrize("g_offset", [0, _INT32_MAX - _N_LANES // 2],
+                         ids=["lanes_from_0", "lanes_past_int32"])
+@pytest.mark.parametrize("t", [
+    0, _INT32_MAX, crng.wrap_i32(2 ** 31 + 12_345),
+    crng.wrap_i32(2 ** 31 - 7) + np.arange(_N_LANES, dtype=np.int64)],
+    ids=["t0", "t_int32_max", "t_wrapped", "t_per_lane"])
+@pytest.mark.parametrize("seed", [
+    0, 1, -1, _INT32_MIN, _INT32_MAX,
+    int(np.random.default_rng(2014).integers(_INT32_MIN, _INT32_MAX))])
+def test_counter_uniform_host_bit_equal_to_device(seed, t, g_offset):
+    """The host twin hashes exactly the device's words: same uint32 wrap
+    for seeds, ticks and lanes outside int32, same fmix32 rounds, same
+    mantissa fill."""
+    lanes = g_offset + np.arange(_N_LANES, dtype=np.int64)
+    dev = np.asarray(crng.counter_uniform(
+        seed, jnp.asarray(np.asarray(t).astype(np.int32)),
+        jnp.asarray(lanes.astype(np.int32))))
+    host = crng.counter_uniform_host(seed, t, lanes)
+    assert isinstance(host, np.ndarray) and host.dtype == np.float32
+    assert host.shape == dev.shape == (_N_LANES,)
+    np.testing.assert_array_equal(host.view(np.int32), dev.view(np.int32))
+
+
 # --------------------------------------------------------- property testing
 if HAS_HYPOTHESIS:
     stream_strat = st.lists(

@@ -27,8 +27,11 @@ import time
 import numpy as np
 import pytest
 import jax
+import jax.numpy as jnp
 
 from repro.api import FleetSpec, QuantileFleet, TopologySpec
+from repro.core import program as program_mod
+from repro.core import rng as crng
 from repro.core.program import make_program
 from repro.data.pipeline import DataConfig, SyntheticCorpus, \
     prefetch_to_device
@@ -229,6 +232,104 @@ def test_dp_program_fleet_is_not_double_noised():
                            tenants=[TenantPolicy("ext", epsilon=1.0)])
     svc.ingest(_chunks(n=1)[0])
     np.testing.assert_array_equal(svc.query(), svc.query(tenant="ext"))
+
+
+def _device_dp_release(m, epsilon, seed, t_next, lanes):
+    """The Laplace release with its uniforms hashed by the device kernel's
+    `counter_uniform`: the bits the host release must keep."""
+    u = np.asarray(crng.counter_uniform(
+        crng.wrap_i32(int(seed) ^ program_mod._DP_SALT),
+        jnp.asarray(t_next, jnp.int32), jnp.asarray(lanes, jnp.int32)),
+        np.float64)
+    c = u - 0.5
+    noise = -(1.0 / epsilon) * np.sign(c) * np.log(
+        np.maximum(1.0 - 2.0 * np.abs(c), np.finfo(np.float64).tiny))
+    return (np.asarray(m, np.float64) + noise).astype(np.float32)
+
+
+def test_dp_release_makes_no_transfer_and_keeps_its_bits():
+    """The DP release runs on host data alone: under a transfer guard that
+    refuses every host<->device copy, `_query_dp` and
+    `Snapshot.estimate_dp` answer in numpy, bit-equal to the release whose
+    uniforms the device hashed — across the release's block boundaries,
+    on a scalar and a per-lane clock."""
+    eps, seed, n = 0.5, 2 ** 31 - 3, 200_003     # several release blocks
+    m = np.random.default_rng(7).normal(size=n).astype(np.float32)
+    lanes = 2 ** 31 - 2048 + np.arange(n, dtype=np.int64)
+    clocks = (np.int32(1_000_003),
+              (np.arange(n) * 7 + 2 ** 31 - 5 * n).astype(np.int32))
+    want = [_device_dp_release(m, eps, seed, t, lanes.astype(np.int32))
+            for t in clocks]
+    prog = make_program("2u-dp", epsilon=eps)
+
+    spec = _spec("fused", quantiles=(0.5, 0.9, 0.99))
+    fleet = QuantileFleet.create(spec, seed=11)
+    for c in _chunks(seed=2, n=2):
+        fleet = fleet.ingest(c)
+    snap = Snapshot.capture(fleet)
+    plane = _device_dp_release(snap.m_planes[0], eps, snap.seed,
+                               snap.t_next, snap.lanes).reshape(G, 3)
+
+    with jax.transfer_guard("disallow"):
+        got = [prog.run_query((m,), t_next=t, seed=seed, lanes=lanes)
+               for t in clocks]
+        got_plane = snap.estimate_dp(eps)
+        got_cols = [snap.estimate_dp(eps, quantile=q)
+                    for q in spec.quantiles]
+    for out in (*got, got_plane, *got_cols):
+        assert isinstance(out, np.ndarray) and out.dtype == np.float32
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g.view(np.int32), w.view(np.int32))
+    np.testing.assert_array_equal(got_plane.view(np.int32),
+                                  plane.view(np.int32))
+    for qi, col in enumerate(got_cols):
+        np.testing.assert_array_equal(col.view(np.int32),
+                                      plane[:, qi].view(np.int32))
+
+
+def _per_lane_clock_fleet(spec):
+    fleet = QuantileFleet.create(spec, seed=13, per_lane_clock=True)
+    rng = np.random.default_rng(1)
+    n = spec.num_lanes
+    for _ in range(30):
+        lanes = rng.choice(n, size=n // 3, replace=False).astype(np.int32)
+        fleet = fleet.tick_lanes_sparse(
+            lanes, rng.normal(size=lanes.size).astype(np.float32))
+    return fleet
+
+
+@pytest.mark.parametrize("case", ["2u", "2u-window", "2u-dp",
+                                  "2u-window-per-lane-clock"])
+def test_one_quantile_read_is_its_column_of_the_plane(case):
+    """A read with `quantile=` runs the release over that target's lanes
+    alone (lane g·Q + qi); it must equal the full plane's column bit for
+    bit, for the DP-gated read, the trusted read, and a `2u-dp` fleet's
+    own release, on a scalar or a per-lane clock."""
+    family = case.replace("-per-lane-clock", "")
+    prog = make_program(family, window=24) if family == "2u-window" \
+        else make_program(family, epsilon=0.7) if family == "2u-dp" \
+        else make_program(family)
+    spec = _spec("fused", program=prog, quantiles=(0.5, 0.9, 0.99))
+    if case.endswith("per-lane-clock"):
+        fleet = _per_lane_clock_fleet(spec)
+        assert np.unique(fleet.query_view()[1]).size > 1
+    else:
+        fleet = QuantileFleet.create(spec, seed=3)
+        for c in _chunks(seed=4, n=3):
+            fleet = fleet.ingest(c)
+    snap = Snapshot.capture(fleet)
+    reads = [(snap.estimate_dp(0.5), lambda q: snap.estimate_dp(0.5, q)),
+             (snap.estimate(), snap.estimate),
+             (fleet.estimate(), fleet.estimate)]
+    for plane, read in reads:
+        assert plane.shape == (G, 3)
+        for qi, q in enumerate(spec.quantiles):
+            col = read(q)
+            assert col.shape == (G,)
+            np.testing.assert_array_equal(col.view(np.int32),
+                                          plane[:, qi].view(np.int32))
+    if family == "2u-dp":           # its own release, not a second draw
+        np.testing.assert_array_equal(snap.estimate_dp(0.5), fleet.estimate())
 
 
 # ------------------------------------------------------------- put-ahead
