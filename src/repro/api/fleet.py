@@ -55,6 +55,7 @@ fleets do.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Iterable, Optional, Tuple, Union
@@ -266,11 +267,43 @@ class QuantileFleet:
             self, state=self._place(self.spec, healed)), rep
 
     # ---------------------------------------------------------- block ingest
+    @property
+    def item_sharding(self) -> Optional[jax.sharding.Sharding]:
+        """How `stage` places a chunk: for a lane-sharded fleet each
+        device's group columns on that device (`NamedSharding(mesh,
+        P(None, axis))`, over the columns padded to whole shards); None,
+        the default device, for the single placement and a 2-D mesh (it
+        routes row slabs itself)."""
+        if isinstance(self.state, ShardedGroupFleet):
+            return self.state.item_sharding
+        return None
+
+    def stage(self, items, shard_span=contextlib.nullcontext) -> Array:
+        """A [t, G] chunk put where `ingest` reads it, the put-ahead step of
+        the served path. The single placement and a 2-D mesh take it whole
+        on the default device (`jax.device_put`); a lane-sharded fleet pads
+        its columns to whole shards and puts each device's columns on that
+        device, so no device holds a whole chunk, with `shard_span()`
+        around the wait for each device's columns."""
+        if isinstance(self.state, ShardedGroupFleet):
+            return self.state._pad_items(self._as_items(items), shard_span)
+        return jax.device_put(items)
+
     def _as_items(self, items) -> Array:
-        items = jnp.asarray(items, jnp.float32)
+        width = (self.num_groups,)
+        sharded = isinstance(self.state, ShardedGroupFleet)
+        if sharded:
+            # A staged chunk carries the shards' pad columns.
+            width += (self.state.padded_groups // self.num_quantiles,)
+        if sharded and not isinstance(items, jax.Array):
+            # Host columns stay on the host: the sharded fleet places each
+            # shard's columns on its own device.
+            items = np.asarray(items, np.float32)
+        else:
+            items = jnp.asarray(items, jnp.float32)
         if items.ndim == 1:
             items = items[:, None]
-        if items.ndim != 2 or items.shape[1] != self.num_groups:
+        if items.ndim != 2 or items.shape[1] not in width:
             raise ValueError(
                 f"items shape {items.shape} != [t, {self.num_groups}]")
         return items

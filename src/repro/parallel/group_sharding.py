@@ -16,14 +16,16 @@ of its column 0 (`g_offset = axis_index * shard_size`) hashes exactly the
 uniforms the unsharded fleet would — so any mesh shape, any chunking, and
 any ragged-G padding reproduce the single-device trajectory bit-for-bit.
 
-Ragged G: the fleet pads G up to a multiple of the mesh size. Pad lanes sit
-at the global tail (real groups keep their absolute indices), carry dummy
-state, and receive NaN items — a bit-exact no-op tick — then are dropped on
-read. The counter hash is stateless, so pad lanes "consuming" uniforms at
-tail keys perturbs nothing.
+Ragged G: the fleet pads its lanes up to a multiple of mesh size × lanes
+per group, so every shard holds whole groups. Pad lanes sit at the global
+tail (real groups keep their absolute indices), carry dummy state, and
+receive NaN items — a bit-exact no-op tick — then are dropped on read.
+The counter hash is stateless, so pad lanes "consuming" uniforms at tail
+keys perturbs nothing.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Iterable, Optional, Union
@@ -70,34 +72,50 @@ def _sketch_from_planes(program, planes, quantile) -> GroupedQuantileSketch:
                                  drift=program.drift, **fields)
 
 
-# One jitted shard_map per (mesh, program, shard width, chunking) — cached
-# so repeated ingest calls hit the same compiled executable. Meshes hash by
-# device list + axis names, so a fleet reuses its entry across calls. The
-# ONE body's operand width derives from the program's StateLayout — a 1U
-# fleet moves one plane, a windowed 2U fleet six; no placeholder [Gp]
-# arrays ever ride along (e9 gates the vanilla hot path's scaling), and
-# the old 3-plane/6-plane body fork is gone.
+# One jitted shard_map per (mesh, program, shard width, chunking, fan-out) —
+# cached so repeated ingest calls hit the same compiled executable. Meshes
+# hash by device list + axis names, so a fleet reuses its entry across
+# calls. The ONE body's operand width derives from the program's
+# StateLayout — a 1U fleet moves one plane, a windowed 2U fleet six; no
+# placeholder [Gp] arrays ever ride along (e9 gates the vanilla hot path's
+# scaling). Each shard takes its own [T, Gp/(n·Q)] group columns and runs
+# the single-device `streaming.ingest_array` on them, so the Q-fold
+# group→lane fan-out happens on every device inside the same code the
+# unsharded fleet runs. The jitted function is named `sharded_ingest`: its
+# executable is `jit_sharded_ingest` in a profile.
 @functools.lru_cache(maxsize=None)
 def _sharded_ingest_fn(mesh: Mesh, axis: str, program, shard_g: int,
-                       chunk_t: int):
+                       chunk_t: int, lanes_per_group: int):
     n = program.layout.num_planes
     state_spec = P(axis)
 
-    def body(items, quantile, seed, t0, g0_base, *planes):
+    def sharded_ingest(items, quantile, seed, t0, g0_base, *planes):
         # g0_base shifts every shard when THIS WHOLE FLEET is itself a
         # column slice of a larger one (the facade cursor's g_offset).
         g0 = g0_base + jax.lax.axis_index(axis) * shard_g
         local = _sketch_from_planes(program, planes, quantile)
         out = streaming.ingest_array(local, items, seed=seed, chunk_t=chunk_t,
-                                     g_offset=g0, t_offset=t0)
+                                     g_offset=g0, t_offset=t0,
+                                     lanes_per_group=lanes_per_group)
         return out.planes()
 
     fn = jax.shard_map(
-        body, mesh=mesh,
+        sharded_ingest, mesh=mesh,
         in_specs=(P(None, axis), state_spec, P(), P(), P())
         + (state_spec,) * n,
         out_specs=(state_spec,) * n, check_vma=False)
     return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def _stack_rows_fn(mesh: Mesh, axis: str):
+    """T [Gp] rows cut over `axis` by column, stacked into the [T, Gp]
+    chunk cut the same way: each device stacks its own pieces."""
+    def stack_rows(*rows):
+        return jnp.stack(rows)
+
+    return jax.jit(stack_rows,
+                   out_shardings=NamedSharding(mesh, P(None, axis)))
 
 
 @jax.tree_util.register_dataclass
@@ -113,10 +131,12 @@ class ShardedGroupFleet:
     When the sketch is a multi-quantile lane plane (`lanes_per_group` = Q >
     1, see GroupedQuantileSketch.create_lanes / repro.api.QuantileFleet),
     the FLATTENED lane axis is what shards: `num_groups` counts real lanes,
-    a shard's `g_offset` is its absolute lane offset, and `_pad_items`
-    accepts [T, G] group columns which it fans out Q-fold on device before
-    placement. The counter RNG keys on absolute lane ids, so estimates are
-    invariant to how lanes land on devices.
+    a shard's `g_offset` is its absolute lane offset, and ingest takes
+    [T, G] group columns. Lanes pad to a multiple of mesh size × Q, so each
+    shard holds whole groups: `_pad_items` places each shard's group
+    columns on its own device (`item_sharding`), and every shard fans its
+    columns out Q-fold itself. The counter RNG keys on absolute lane ids,
+    so estimates are invariant to how lanes land on devices.
 
     Registered as a pytree (sketch leaves dynamic, layout static) so a
     fleet can ride inside jitted steps and checkpoint pytrees.
@@ -142,6 +162,12 @@ class ShardedGroupFleet:
     @property
     def shard_groups(self) -> int:
         return self.sketch.num_groups // self.mesh.shape[self.axis]
+
+    @property
+    def item_sharding(self) -> NamedSharding:
+        """Where ingest wants its [T, Gp/Q] padded group columns: split by
+        column over the mesh, each shard's columns on its own device."""
+        return NamedSharding(self.mesh, P(None, self.axis))
 
     def memory_words(self) -> int:
         """Persistent words per group — 1 (1U) or 2 (2U), same as unsharded."""
@@ -175,8 +201,9 @@ class ShardedGroupFleet:
         if g % lanes_per_group:
             raise ValueError(f"sketch lanes {g} not divisible by "
                              f"lanes_per_group={lanes_per_group}")
-        n = mesh.shape[axis]
-        gp = -(-g // n) * n
+        # Whole groups per shard: pad lanes to a multiple of n·Q.
+        unit = mesh.shape[axis] * lanes_per_group
+        gp = -(-g // unit) * unit
         sharding = NamedSharding(mesh, P(axis))
 
         layout = sketch.program.layout
@@ -198,35 +225,61 @@ class ShardedGroupFleet:
                                  axis=axis, lanes_per_group=lanes_per_group)
 
     # ---------------------------------------------------------------- ingest
-    def _pad_items(self, items) -> Array:
-        """Pad columns to the mesh multiple and place on the mesh. Accepts
-        [T, G] group columns (fanned out Q-fold on device for a lane-plane
-        fleet), [T, L] real lanes, or an already-padded/placed [T, Gp]
-        array — idempotent, so callers may pre-place items once and
-        re-ingest them (device_put onto the sharding they already carry is
-        a no-op)."""
-        items = jnp.asarray(items, jnp.float32)
+    def _pad_items(self, items, shard_span=contextlib.nullcontext) -> Array:
+        """[T, G] group columns, padded with NaN columns to the mesh's
+        multiple and placed by `item_sharding`. Host columns go to their
+        shards row by row (`_stage_rows`), never whole onto one device;
+        `shard_span()` wraps the wait for each device's rows. Idempotent: an
+        array already padded and placed (a pre-placed benchmark input, a
+        chunk the ingest pipeline staged) passes through as it is."""
+        if not isinstance(items, jax.Array):
+            items = np.asarray(items, np.float32)
         if items.ndim == 1:
             items = items[:, None]
-        gp = self.padded_groups
-        q = self.lanes_per_group
-        cols = self.num_groups // q
-        ok = {self.num_groups, gp} | ({cols} if q > 1 else set())
-        if items.ndim != 2 or items.shape[1] not in ok:
+        cols = self.num_groups // self.lanes_per_group
+        padded = self.padded_groups // self.lanes_per_group
+        if items.ndim != 2 or items.shape[1] not in (cols, padded):
             raise ValueError(
                 f"items shape {items.shape} != [T, {cols}]")
-        if q > 1 and items.shape[1] == cols:
-            items = jnp.repeat(items, q, axis=1)
-        if items.shape[1] != gp:  # pad lanes get NaN items: bit-exact no-ops
-            items = jnp.pad(items, ((0, 0), (0, gp - items.shape[1])),
-                            constant_values=jnp.nan)
-        return jax.device_put(items, NamedSharding(self.mesh, P(None, self.axis)))
+        pad = ((0, 0), (0, padded - items.shape[1]))
+        if isinstance(items, jax.Array):
+            items = items.astype(jnp.float32)
+            if pad[1][1]:  # pad lanes get NaN items: bit-exact no-ops
+                items = jnp.pad(items, pad, constant_values=jnp.nan)
+            return jax.device_put(items, self.item_sharding)
+        if pad[1][1]:
+            items = np.pad(items, pad, constant_values=np.nan)
+        return self._stage_rows(np.ascontiguousarray(items), shard_span)
+
+    def _stage_rows(self, items: np.ndarray, shard_span) -> Array:
+        """Row-major host `items` placed by `item_sharding`, returning once
+        every device holds its slab. A device's [T, Gp/n] column slab is T
+        contiguous row pieces of the host array: each is sent as it lies in
+        host memory, and the device stacks them, so no transfer reads a
+        strided host view. On a TPU v5e 2x2, a 4 GiB chunk sent as strided
+        slabs reached its chips in 0.17 s in some processes and 1.6-1.8 s
+        in others; as row pieces, in 0.18 s in every run so far (PERF.md
+        section 5; benchmarks/stage_probe.py times both)."""
+        width = items.shape[1]
+        rows = NamedSharding(self.mesh, P(self.axis))
+        sent = {device: jax.device_put([row[cols] for row in items], device)
+                for device, (cols,) in
+                rows.addressable_devices_indices_map((width,)).items()}
+        for pieces in sent.values():
+            with shard_span():
+                jax.block_until_ready(pieces)
+        by_row = [jax.make_array_from_single_device_arrays(
+            (width,), rows, [pieces[r] for pieces in sent.values()])
+            for r in range(items.shape[0])]
+        return jax.block_until_ready(
+            _stack_rows_fn(self.mesh, self.axis)(*by_row))
 
     def _run_sharded(self, items: Array, seed, t0, chunk_t: int,
                      g_offset=0) -> "ShardedGroupFleet":
         sk = self.sketch
         fn = _sharded_ingest_fn(self.mesh, self.axis, sk.program,
-                                self.shard_groups, chunk_t)
+                                self.shard_groups, chunk_t,
+                                self.lanes_per_group)
         scalars = (jnp.asarray(seed, jnp.int32), jnp.asarray(t0, jnp.int32),
                    jnp.asarray(g_offset, jnp.int32))
         planes = fn(items, sk.quantile, *scalars, *sk.planes())

@@ -19,16 +19,24 @@ readers. Blocking per chunk is deliberate: it gives honest per-chunk
 latency numbers and a real publication point — an unbounded dispatch queue
 would "publish" fleets whose device work hasn't happened yet.
 
+Where a chunk is staged is the fleet's to say (`QuantileFleet.stage`):
+the single placement takes it whole with `jax.device_put`, as before; a
+lane-sharded fleet puts each device's columns on that device, so no
+device ever holds a whole chunk.
+
 Spans (`telemetry.span`, recorded while a profiler capture runs), each
 keyed by the chunk's number: `ingest.stage` (the put-ahead thread's
-`transfer` of one chunk; `jax.device_put` returns once the copy is under
-way), `ingest.wait_staged` (the apply loop waiting
-for the next staged chunk), `ingest.apply` (`fleet.ingest` plus the
-wait on its result) and its child `ingest.block` (that wait alone: the
-device working on the chunk).
+`fleet.stage` of one chunk; `jax.device_put` returns once the copy is
+under way, a lane-sharded stage once every device holds its columns)
+and, on a lane-sharded fleet, its children `ingest.stage.shard` (the
+wait for one device's columns), `ingest.wait_staged` (the apply loop
+waiting for the next staged chunk), `ingest.apply` (`fleet.ingest` plus
+the wait on its result) and its child `ingest.block` (that wait alone:
+the device working on the chunk).
 
-Telemetry (optional, duck-typed): items/chunks counters and each
-`ingest.apply` span's duration into the `ingest_chunk_ms` histogram.
+Telemetry (optional, duck-typed): items/chunks counters, the
+`ingest.bytes_staged` counter, and each `ingest.apply` span's duration
+into the `ingest_chunk_ms` histogram.
 """
 from __future__ import annotations
 
@@ -45,7 +53,10 @@ from .telemetry import span
 
 
 def _block_on(fleet: QuantileFleet) -> None:
-    """Wait for the fleet's device work (publication barrier)."""
+    """Wait for the fleet's device work (publication barrier). Every state
+    plane is an output of one executable, so waiting on `m` waits for all
+    of it; on a sharded fleet `m` is the global array, whose readiness is
+    that of every shard on every device."""
     state = fleet.state
     sk = getattr(state, "sketch", state)   # sharded fleets wrap the sketch
     jax.block_until_ready(sk.m)
@@ -55,15 +66,11 @@ class IngestPipeline:
     """Double-buffered host→device chunk ingest over one QuantileFleet.
 
     `depth` is the put-ahead queue bound (1 = classic double buffering).
-    `transfer=None` disables device staging (chunks pass through as-is) —
-    useful when the source already yields device arrays.
     """
 
-    def __init__(self, depth: int = 1, telemetry=None,
-                 transfer: Optional[Callable] = jax.device_put):
+    def __init__(self, depth: int = 1, telemetry=None):
         self.depth = int(depth)
         self.telemetry = telemetry
-        self._transfer = transfer
         self._applied = 0
 
     def run(self, fleet: QuantileFleet, chunks: Iterable,
@@ -75,18 +82,21 @@ class IngestPipeline:
         # Chunk numbers count every chunk this pipeline has applied; staging
         # runs ahead in its own thread but in the same order.
         first = self._applied
-        if self._transfer is None:
-            staged = iter(chunks)
-        else:
-            base = self._transfer
-            numbers = itertools.count(first)
+        numbers = itertools.count(first)
+        place = fleet.stage
 
-            def transfer(x):
-                with span("ingest.stage", key=next(numbers)):
-                    return base(x)
+        def shard_span():
+            return span("ingest.stage.shard")
 
-            staged = prefetch_to_device(iter(chunks), depth=self.depth,
-                                        transfer=transfer)
+        def transfer(x):
+            with span("ingest.stage", key=next(numbers)):
+                out = place(x, shard_span)
+            if tel is not None:
+                tel.count("ingest.bytes_staged", out.nbytes)
+            return out
+
+        staged = prefetch_to_device(iter(chunks), depth=self.depth,
+                                    transfer=transfer)
         for k in itertools.count(first):
             with span("ingest.wait_staged", key=k) as waited:
                 chunk = next(staged, None)

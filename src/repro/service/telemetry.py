@@ -4,14 +4,14 @@ records.
 
 The counters are plain thread-safe dict increments (ingest and query
 threads both write them): `items_ingested`, `chunks_ingested`,
-`queries_served`, `queries_stalled`, `quarantined_lanes`; callers may add
-their own. The latency distribution is where we eat our own dogfood:
-per-metric p50/p99 come from a tiny scalar-clock
-`repro.api.QuantileFleet` — one group per latency metric, quantile lanes
-(0.5, 0.99) — fed NaN-padded [rounds, metrics] blocks (NaN is the stack's
-bit-exact no-op padding contract), so the service's *telemetry* costs 2
-words per (metric × quantile) lane, exactly the paper's claim applied to
-ourselves.
+`ingest.bytes_staged`, `queries_served`, `queries_stalled`,
+`quarantined_lanes`; callers may add their own. The latency distribution
+is where we eat our own dogfood: per-metric p50/p99 come from a tiny
+scalar-clock `repro.api.QuantileFleet` — one group per latency metric,
+quantile lanes (0.5, 0.99) — fed NaN-padded [rounds, metrics] blocks (NaN
+is the stack's bit-exact no-op padding contract), so the service's
+*telemetry* costs 2 words per (metric × quantile) lane, exactly the
+paper's claim applied to ourselves.
 
 Determinism note: a latency lane's trajectory is a pure function of the
 sequence of (flush boundary, observed values) — the counter RNG keys each
@@ -21,8 +21,9 @@ Wall-clock latencies themselves are of course not deterministic; the
 MACHINERY is.
 
 Spans. `span(name, key=None)` times one region of the served path (the
-ingest pipeline's stage, wait, apply and block; a read, its snapshot and
-its DP release); the histograms above take their durations from it. To
+ingest pipeline's stage and each device's part of a sharded stage, wait,
+apply and block; a read, its snapshot and its DP release); the histograms
+above take their durations from it. To
 see the spans themselves, start a `jax.profiler` capture
 (`jax.profiler.start_trace(dir)` ... `stop_trace()`). While one runs,
 each span is also a `TraceAnnotation` in the profile's host plane, on the

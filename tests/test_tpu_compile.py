@@ -112,3 +112,66 @@ def test_donated_sparse_round_compiles_in_place(one_chip, family):
     assert text.count(" scatter(") >= layout.num_planes + 1
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * SPARSE_L
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_sharded_ingest_compiles_for_four_chips(topo, monkeypatch):
+    """The lane-sharded ingest of a 2^24-group, two-quantile 2U fleet on a
+    v5e 2x2: each chip takes its own [64, 2^22] column slab and fans it
+    out to its 2^23 lanes through the DMA kernel, with no collective, in
+    one chip's memory. The dispatch asks the host for its platform, so the
+    test names the chip it compiles for."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.parallel import group_sharding
+
+    monkeypatch.setattr(kernel_ops, "detect_platform", lambda: "tpu")
+    monkeypatch.setattr(kernel_ops, "supports_compiled_kernels",
+                        lambda: True)
+    monkeypatch.setattr(kernel_ops, "_tuned_blocks",
+                        lambda prog, g, t: autotune_blocks(
+                            prog, g, t, hw=hw_for("tpu-v5e")))
+    groups, q, shards = 4 * DENSE_G, 2, 4
+    mesh = Mesh(topo.devices[:shards], (group_sharding.GROUP_AXIS,))
+    prog = program_mod.make_program("2u")
+    fn = group_sharding._sharded_ingest_fn(
+        mesh, group_sharding.GROUP_AXIS, prog, groups * q // shards,
+        DENSE_T, q)
+    items = jax.ShapeDtypeStruct(
+        (DENSE_T, groups), jnp.float32,
+        sharding=NamedSharding(mesh, P(None, group_sharding.GROUP_AXIS)))
+    lane = jax.ShapeDtypeStruct(
+        (groups * q,), jnp.float32,
+        sharding=NamedSharding(mesh, P(group_sharding.GROUP_AXIS)))
+    scalar = jax.ShapeDtypeStruct((), jnp.int32,
+                                  sharding=NamedSharding(mesh, P()))
+    lowered = fn.lower(items, lane, scalar, scalar, scalar,
+                       *(lane,) * prog.layout.num_planes)
+    assert lowered.as_text().startswith("module @jit_sharded_ingest")
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not any(op in text for op in (
+        "all-gather", "all-reduce", "all-to-all", "collective-permute"))
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_row_stack_compiles_for_four_chips(topo):
+    """The lane-sharded staging's device half at the four-chip cell's size:
+    64 rows of 2^24 columns, each cut four ways, stack into the [64, 2^24]
+    chunk cut the same way. Each chip stacks its own [64, 2^22] slab, with
+    no collective, in one chip's memory."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.parallel import group_sharding
+
+    axis = group_sharding.GROUP_AXIS
+    mesh = Mesh(topo.devices[:4], (axis,))
+    row = jax.ShapeDtypeStruct((4 * DENSE_G,), jnp.float32,
+                               sharding=NamedSharding(mesh, P(axis)))
+    lowered = group_sharding._stack_rows_fn(mesh, axis).lower(
+        *(row,) * DENSE_T)
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert not any(op in text for op in (
+        "all-gather", "all-reduce", "all-to-all", "collective-permute"))
+    assert _device_bytes(compiled) < 3 * DENSE_T * DENSE_G * 4
